@@ -71,6 +71,7 @@ def run():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = SRC
+    env["JAX_PLATFORMS"] = "cpu"   # a CPU-device model, never the TPU
     out = subprocess.run([sys.executable, "-c", MEASURE], env=env,
                          capture_output=True, text=True, timeout=900)
     if out.returncode != 0:

@@ -58,6 +58,7 @@ def measure(mode: str, ndev: int) -> float:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
     env["PYTHONPATH"] = SRC
+    env["JAX_PLATFORMS"] = "cpu"   # a CPU-device model, never the TPU
     out = subprocess.run(
         [sys.executable, "-c", SCRIPT.format(ndev=ndev, mode=mode)],
         env=env, capture_output=True, text=True, timeout=1200)

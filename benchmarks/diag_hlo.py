@@ -90,6 +90,7 @@ def main():
         overrides[k] = (v == "True") if v in ("True", "False") else int(v)
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
+    env["JAX_PLATFORMS"] = "cpu"   # a CPU-device model, never the TPU
     out = subprocess.run(
         [sys.executable, "-c", INNER.format(arch=args.arch, shape=args.shape,
                                             top=args.top,

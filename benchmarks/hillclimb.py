@@ -85,6 +85,7 @@ print("JSON::" + json.dumps(rec))
 def run_variant(arch, shape, overrides):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
+    env["JAX_PLATFORMS"] = "cpu"   # a CPU-device model, never the TPU
     out = subprocess.run(
         [sys.executable, "-c",
          RUN_ONE.format(arch=arch, shape=shape, overrides=overrides)],

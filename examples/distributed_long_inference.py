@@ -43,6 +43,7 @@ def run(ndev: int, n_res: int):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
     env["PYTHONPATH"] = SRC
+    env["JAX_PLATFORMS"] = "cpu"   # a CPU-device model, never the TPU
     out = subprocess.run([sys.executable, "-c", INNER.format(n_res=n_res)],
                          env=env, capture_output=True, text=True, timeout=900)
     print(out.stdout.strip() or out.stderr[-400:])

@@ -17,9 +17,12 @@ from repro.configs import get_config
 from repro.configs.alphafold import SMOKE
 from repro.data import protein_batches
 from repro.exec import ExecutionPlan, FastFold
+from repro.launch.cache import enable_compilation_cache
 from repro.models.decoder import init_model
 from repro.serving.engine import ServingEngine
 from repro.train.loop import make_train_step
+
+enable_compilation_cache()
 
 # --- 1. AlphaFold inference -------------------------------------------------
 print("== AlphaFold (reduced) folding inference ==")

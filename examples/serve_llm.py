@@ -36,6 +36,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, list_archs
+from repro.launch.cache import enable_compilation_cache
 from repro.models.decoder import init_model
 from repro.resilience import FaultSpec, RetryPolicy, inject_faults
 from repro.serving.engine import ServingEngine
@@ -64,6 +65,7 @@ def main():
                          "retry / quarantine / degradation handling")
     ap.add_argument("--fault-seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compilation_cache()
 
     cfg = get_config(args.arch, reduced_variant=True)
     params = init_model(jax.random.PRNGKey(0), cfg)

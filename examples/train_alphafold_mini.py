@@ -19,6 +19,7 @@ from repro.configs import alphafold as afc
 from repro.data import protein_batches
 from repro.exec.plan import PRESETS, preset
 from repro.exec.session import FastFold
+from repro.launch.cache import enable_compilation_cache
 from repro.layers.params import count_params
 from repro.train.checkpoint import latest_checkpoint, restore_checkpoint, \
     save_checkpoint
@@ -33,11 +34,12 @@ def main():
     ap.add_argument("--n-res", type=int, default=16)
     ap.add_argument("--n-seq", type=int, default=8)
     ap.add_argument("--lr", type=float, default=1e-3)
-    ap.add_argument("--ckpt-dir", default="/tmp/af_mini_ckpt")
+    ap.add_argument("--ckpt-dir", default="checkpoints/af_mini")
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--plan", default="default", choices=sorted(PRESETS),
                     help="ExecutionPlan preset the session binds")
     args = ap.parse_args()
+    enable_compilation_cache()
 
     cfg = afc.SMOKE if args.config == "smoke" else afc.MINI
     # The FastFold facade binds (config, plan) once: the train-loss closure it

@@ -95,7 +95,7 @@ class CellResult:
 
 
 def _mesh_ctx(mesh):
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+    return jax.set_mesh(mesh)
 
 
 def _compile_artifact(name: str, fn, *args) -> CompiledArtifact:

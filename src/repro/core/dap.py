@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.dist import ShardMapDist, batch_spec, shard_map_compat
+from repro.core.dist import ShardMapDist, batch_spec, unchecked_shard_map
 from repro.core import evoformer as evo
 
 
@@ -67,7 +67,7 @@ def dap_evoformer_stack(mesh, cfg: evo.EvoformerConfig, *, train: bool = False,
             dist=dist, cfg=cfg, rng=None, train=train, remat=remat,
         )
 
-    return shard_map_compat(
+    return unchecked_shard_map(
         local_fn,
         mesh,
         (P(), s["msa"], s["pair"], s["msa_mask"], s["seq_mask"],

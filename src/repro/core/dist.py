@@ -59,30 +59,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6 promotes shard_map out of experimental
-    from jax import shard_map as _shard_map_fn  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map_fn
-
-
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """shard_map without replication checking, across jax versions
-    (``check_rep`` was renamed ``check_vma``)."""
-    try:
-        return _shard_map_fn(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return _shard_map_fn(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-
-
-def named_axis_size(axis: str) -> int:
-    """Static size of a named mapped axis, across jax versions: jax>=0.5 has
-    jax.lax.axis_size; 0.4.x exposes it via jax.core.axis_frame."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    frame = jax.core.axis_frame(axis)
-    return frame if isinstance(frame, int) else frame.size
+def unchecked_shard_map(fn, mesh, in_specs, out_specs):
+    """``jax.shard_map`` without the varying-manual-axes check: the fused
+    kernels' custom VJPs and the DAP collectives are written for local
+    shards, which that check cannot see through."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _local_fused_attention(q, k, v, *, bias=None, mask=None, scale=None,
@@ -160,7 +142,7 @@ class ShardMapDist:
 
     @property
     def axis_size(self) -> int:
-        return named_axis_size(self.axis)
+        return jax.lax.axis_size(self.axis)
 
     def all_to_all(self, x, *, split_axis: int, concat_axis: int):
         # Swap which axis is sharded: locally split `split_axis`, concat shards
@@ -290,7 +272,7 @@ class GspmdDist:
                                           mask=m_, scale=scale,
                                           kv_tile=kv_tile)
 
-        return shard_map_compat(local_fn, self.mesh, tuple(in_specs), io)(
+        return unchecked_shard_map(local_fn, self.mesh, tuple(in_specs), io)(
             *args)
 
     def sharded_triangle_supported(self, i_extent: int) -> bool:
@@ -319,7 +301,7 @@ class GspmdDist:
             return _local_fused_triangle(al, g_, mk, bf, gam, bet, w_, bo,
                                          gl, gb, tile=tile)
 
-        return shard_map_compat(local_fn, self.mesh, in_specs, row4)(
+        return unchecked_shard_map(local_fn, self.mesh, in_specs, row4)(
             a_lin, ga, mask, b_full, gamma, beta, w_out, b_out, g_lin,
             g_bias)
 
@@ -343,7 +325,7 @@ class GspmdDist:
         def local_fn(a_, bf, ma, mb, w_, bi):
             return _local_fused_opm(a_, bf, ma, mb, w_, bi, tile=tile)
 
-        return shard_map_compat(local_fn, self.mesh, in_specs, out_spec)(
+        return unchecked_shard_map(local_fn, self.mesh, in_specs, out_spec)(
             a, b_full, mask_a, mask_b, w, bias)
 
 

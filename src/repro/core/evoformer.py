@@ -93,13 +93,14 @@ class EvoformerConfig:
     attn_kv_tile: int = 0
     # Tile of the fused triangle-multiplication kernel: the Pallas grid's k
     # accumulation tile and the XLA leg's / backward recompute's j output
-    # block. 0 = leg default (Pallas 64, VMEM-budgeted; XLA/backward j block
-    # 128 — the HBM-visible transient the planner models). Bounds the fp32
-    # product transient at (B, i_loc, tile, c) instead of (B, i_loc, r, c).
+    # block. 0 = leg default (Pallas 128, a multiple of the TPU lane width;
+    # XLA/backward j block 128 — the HBM-visible transient the planner
+    # models). Bounds the fp32 product transient at (B, i_loc, tile, c)
+    # instead of (B, i_loc, r, c).
     tri_k_tile: int = 0
     # Tile of the fused outer-product-mean kernel: Pallas s accumulation
     # tile / XLA-leg j output block / backward recompute block. 0 = leg
-    # default (Pallas 64, XLA/backward 128). Bounds the fp32 outer-product
+    # default (Pallas 128, XLA/backward 128). Bounds the fp32 outer-product
     # transient at (B, i_loc, tile, c_opm^2).
     opm_s_tile: int = 0
     # Let the AutoChunk planner (repro.memory.autochunk) fill any chunk knob

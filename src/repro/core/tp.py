@@ -19,10 +19,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import evoformer as evo
-from repro.core.dist import LocalDist, batch_spec, named_axis_size
+from repro.core.dist import LocalDist, batch_spec, unchecked_shard_map
 from repro.kernels import ops
 from repro.layers.attention import evoformer_attention
 from repro.layers.norms import layer_norm
@@ -57,7 +56,7 @@ def _slice_vec(b, idx, n, groups: int = 1):
 def tp_gated_attention(p_attn, x_n, bias, key_mask, heads, head_dim, axis):
     """Column-parallel QKV/gate, row-parallel output + AllReduce."""
     idx = jax.lax.axis_index(axis)
-    n = named_axis_size(axis)
+    n = jax.lax.axis_size(axis)
     h_loc = heads // n
     dt = x_n.dtype
 
@@ -97,7 +96,7 @@ def tp_gated_attention(p_attn, x_n, bias, key_mask, heads, head_dim, axis):
 def tp_transition(p, x, axis):
     """Column-parallel first linear, row-parallel second + AllReduce."""
     idx = jax.lax.axis_index(axis)
-    n = named_axis_size(axis)
+    n = jax.lax.axis_size(axis)
     x_n = layer_norm(p["ln"], x)
     dt = x_n.dtype
     wi = _slice_cols(p["mlp"]["wi"]["w"], idx, n).astype(dt)
@@ -186,10 +185,5 @@ def tp_evoformer_stack(mesh, cfg: evo.EvoformerConfig, *, remat: bool = True):
     b4 = P(batch_spec(mesh), None, None, None)
     b3 = P(batch_spec(mesh), None, None)
     b2 = P(batch_spec(mesh), None)
-    return shard_map(
-        local_fn,
-        mesh=mesh,
-        in_specs=(P(), b4, b4, b3, b2, b3),
-        out_specs=(b4, b4),
-        check_rep=False,
-    )
+    return unchecked_shard_map(local_fn, mesh, (P(), b4, b4, b3, b2, b3),
+                               (b4, b4))

@@ -18,6 +18,8 @@ Recognized variables:
   REPRO_FORCE_SCAN_ATTN_BWD=1      -> KernelPolicy.attn_bwd = "scan"
   REPRO_FAULT_SEED=<int>           -> default seed of resilience.FaultInjector
                                    (not a plan field; read via fault_seed())
+  JAX_COMPILATION_CACHE_DIR=<dir>  -> persistent compile cache of the entry
+                                   points (read via compilation_cache_dir())
 
 Legacy flags layer on top of the preset, so e.g.
 ``REPRO_PLAN=interpret REPRO_FORCE_TRIANGLE_ORACLE=1`` composes.
@@ -26,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import pathlib
 
 _ENV_VARS = (
     "REPRO_PLAN",
@@ -34,6 +37,9 @@ _ENV_VARS = (
     "REPRO_FORCE_TRIANGLE_ORACLE",
     "REPRO_FORCE_SCAN_ATTN_BWD",
 )
+
+# The checkout this package runs from: src/repro/exec/envcompat.py -> root.
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
 
 # Memoized on the observed env values — re-reads the environment on every
 # call (cheap), rebuilds the plan only when a relevant variable changed.
@@ -84,3 +90,12 @@ def force_host_device_count(n: int) -> None:
     env access confined to this module. This package imports no jax, so
     importing it never triggers backend init."""
     os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+
+
+def compilation_cache_dir() -> str:
+    """Directory of JAX's persistent compilation cache for the entry points:
+    ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it, else a fixed
+    ``.jax_cache`` inside the checkout. The path is part of the cache key, so
+    it never depends on a temp dir, pid or clock."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or str(CHECKOUT / ".jax_cache"))

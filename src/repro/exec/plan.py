@@ -44,6 +44,11 @@ each op family in ``kernels/ops.py`` (``auto`` is the default everywhere):
     ``use_plan`` even though the backward is traced later.
   * Off-TPU, an explicit ``"pallas"`` runs the kernel in interpret mode
     (there is no compiled Pallas backend to target).
+  * Under ``ParallelPolicy(backend="gspmd")`` on TPU, ``"auto"`` resolves
+    softmax / layer_norm / elementwise to their XLA leg: those ops see
+    global, mesh-sharded arrays, and GSPMD cannot partition a Pallas call
+    (attention, triangle and OPM run their kernels inside the dist
+    backend's shard_map).
 
 ``ParallelPolicy`` subsumes the hand-threaded ``dist=`` kwarg (the backend is
 built once via ``make_dist()``), ``MemoryPolicy`` subsumes ``hbm_budget=``

@@ -30,7 +30,7 @@ def _mesh_scope(plan: ExecutionPlan):
     around trace and execution; the other backends need nothing)."""
     mesh = plan.parallel.mesh
     if plan.parallel.backend == "gspmd" and mesh is not None:
-        return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+        return jax.set_mesh(mesh)
     return contextlib.nullcontext()
 
 
@@ -92,6 +92,19 @@ class FastFold:
         fn = jax.jit(impl)
         self._jitted[key] = fn
         return fn
+
+    def lower(self, kind: str, params, batch, rng=None, *,
+              plan: ExecutionPlan | None = None, train: bool = False):
+        """AOT-lower the jitted ``"forward"`` or ``"train_loss"`` entry under
+        the plan's mesh scope. ``.compile()`` on the result gives the program
+        (``memory_analysis()``, ``as_text()``), callable as
+        ``(params, batch, rng)``."""
+        if kind not in ("forward", "train_loss"):
+            raise ValueError(f"FastFold.lower: unknown entry {kind!r}")
+        plan = plan if plan is not None else self.plan
+        with _mesh_scope(plan):
+            return self._get_jitted(kind, plan, train).lower(params, batch,
+                                                             rng)
 
     def forward(self, params, batch, *, rng=None, train: bool = False,
                 plan: ExecutionPlan | None = None):

@@ -32,13 +32,19 @@ Kernel contract (enforced/prepared by ops.fused_attention):
   bias    : (B, H, Sq, Skv) additive, ``N % B == 0`` (each bias batch element
             is shared by N/B consecutive rows of q — the Evoformer pair bias
             shared across the MSA/group axis), or None.
-  mask    : (N, Skv) additive fp32 (0 / NEG_INF-style), or None. Mask values
-            must be finite (use ~-1e9, not -inf).
+  mask    : (N, 1, Skv) additive fp32 (0 / NEG_INF-style), or None — the
+            unit axis keeps the block's second-minor dim equal to the
+            array's (the TPU (8, 128) block rule). Mask values must be
+            finite (use ~-1e9, not -inf).
   kv_len  : true KV length before padding; padded columns are masked to
             ``NEG_INF`` in-kernel so they never win the max nor add to the sum.
 
 Returns ``out (N, H, Sq, D)`` in the input dtype and the fp32 log-sum-exp
-``lse (N, H, Sq)`` that the recompute backward in ops.py needs.
+``lse (N, H, Sq)`` that the recompute backward in ops.py needs. Inside the
+kernels the per-row statistics (lse, delta) and the mask reduction travel as
+``(N, H, 1, S)`` rows: a ``(1, 1, 1, tile)`` block obeys the (8, 128) rule
+where a ``(1, 1, tile)`` block of ``(N, H, S)`` does not, and a row costs at
+most an 8-sublane pad in HBM where a lane-broadcast column would cost 128x.
 
 Grid: ``(N, H, Sq/q_tile, Skv/kv_tile)`` with KV innermost. The fp32 running
 (m, l, acc) state lives in VMEM scratch across the KV sweep; the output block
@@ -57,6 +63,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 NEG_INF = -1e30  # finite: keeps exp(s - m) NaN-free even for all-masked rows
+# In-kernel MXU precision, fixed so a process-wide
+# ``jax_default_matmul_precision`` cannot reach the kernels: Mosaic refuses
+# bf16 operands at fp32 contract precision ("Bad lhs type").
+KERNEL_PRECISION = jax.lax.Precision.DEFAULT
 
 
 def _pad_to(n: int, m: int) -> int:
@@ -90,11 +100,12 @@ def _flash_kernel(*refs, scale: float, kv_len: int, kv_tile: int,
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=KERNEL_PRECISION,
     ) * scale                                         # (q_tile, kv_tile)
     if b_ref is not None:
         s = s + b_ref[0, 0].astype(jnp.float32)
     if mk_ref is not None:
-        s = s + mk_ref[0].astype(jnp.float32)[None, :]
+        s = s + mk_ref[0].astype(jnp.float32)          # (1, kv_tile) row
     # Neutralize KV padding: padded columns must not win the max nor
     # contribute to the sum.
     col = jk * kv_tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -109,6 +120,7 @@ def _flash_kernel(*refs, scale: float, kv_len: int, kv_tile: int,
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=KERNEL_PRECISION,
     )
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -117,7 +129,7 @@ def _flash_kernel(*refs, scale: float, kv_len: int, kv_tile: int,
     def _epilogue():
         l = jnp.maximum(l_ref[:, :1], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = (m_ref[:, :1] + jnp.log(l))[:, 0]
+        lse_ref[0, 0] = (m_ref[:, :1] + jnp.log(l)).reshape(1, -1)
 
 
 @functools.partial(
@@ -162,9 +174,9 @@ def flash_attention_pallas(
         )
         operands.append(bias)
     if has_mask:
-        assert mask is not None and mask.shape == (n, skv)
+        assert mask is not None and mask.shape == (n, 1, skv)
         in_specs.append(
-            pl.BlockSpec((1, kv_tile), lambda i, j, iq, jk: (i, jk))
+            pl.BlockSpec((1, 1, kv_tile), lambda i, j, iq, jk: (i, 0, jk))
         )
         operands.append(mask)
 
@@ -172,17 +184,17 @@ def flash_attention_pallas(
         _flash_kernel, scale=scale, kv_len=kv_len, kv_tile=kv_tile,
         has_bias=has_bias, has_mask=has_mask,
     )
-    return pl.pallas_call(
+    out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, q_tile, d), lambda i, j, iq, jk: (i, j, iq, 0)),
-            pl.BlockSpec((1, 1, q_tile), lambda i, j, iq, jk: (i, j, iq)),
+            pl.BlockSpec((1, 1, 1, q_tile), lambda i, j, iq, jk: (i, j, 0, iq)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((n, h, sq), jnp.float32),
+            jax.ShapeDtypeStruct((n, h, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((q_tile, d), jnp.float32),      # acc
@@ -191,6 +203,7 @@ def flash_attention_pallas(
         ],
         interpret=interpret,
     )(*operands)
+    return out, lse.reshape(n, h, sq)
 
 
 # ---------------------------------------------------------------------------
@@ -310,19 +323,22 @@ def _recompute_ds(q, k, v, do, lse, delta, b_blk, m_blk, *, scale, kv_len,
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=KERNEL_PRECISION,
     ) * scale                                          # (q_tile, kv_tile)
     if b_blk is not None:
         s = s + b_blk.astype(jnp.float32)
     if m_blk is not None:
-        s = s + m_blk.astype(jnp.float32)[None, :]
+        s = s + m_blk.astype(jnp.float32)              # (1, kv_tile) row
     col = jk * kv_tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(col < kv_len, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])                      # (q_tile, kv_tile)
+    # lse/delta arrive as (1, q_tile) rows; the tile needs them as columns.
+    p = jnp.exp(s - lse.reshape(-1, 1))                # (q_tile, kv_tile)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=KERNEL_PRECISION,
     )                                                  # (q_tile, kv_tile)
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta.reshape(-1, 1))
     return p, ds
 
 
@@ -360,6 +376,7 @@ def _bwd_dq_kernel(*refs, scale, kv_len, kv_tile, has_bias, has_mask,
     dq_acc[...] += jax.lax.dot_general(
         ds, k_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=KERNEL_PRECISION,
     ) * scale
     if db_ref is not None:
         # Mesh-local bias group (rep == 1): dbias IS the ds tile — each
@@ -411,10 +428,12 @@ def _bwd_dkv_kernel(*refs, scale, kv_len, kv_tile, has_bias, has_mask):
     dv_acc[...] += jax.lax.dot_general(
         p, do_ref[0, 0].astype(jnp.float32), (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=KERNEL_PRECISION,
     )                                                  # (kv_tile, d)
     dk_acc[...] += jax.lax.dot_general(
         ds, q_ref[0, 0].astype(jnp.float32), (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=KERNEL_PRECISION,
     ) * scale
     if dm_acc is not None:
         dm_acc[...] += jnp.broadcast_to(
@@ -425,7 +444,7 @@ def _bwd_dkv_kernel(*refs, scale, kv_len, kv_tile, has_bias, has_mask):
         dk_ref[0, 0] = dk_acc[...]
         dv_ref[0, 0] = dv_acc[...]
         if dm_ref is not None:
-            dm_ref[0, 0, :] = dm_acc[0, :]
+            dm_ref[0, 0] = dm_acc[0:1, :]
 
 
 def _bwd_dbias_kernel(*refs, scale, kv_len, kv_tile, has_mask):
@@ -487,7 +506,8 @@ def flash_attention_bwd_pallas(
     """Fused flash-attention backward. Pre-padded kernel layout, like the
     forward: q/k/v/do (N, H, S, D) with D a 128-lane multiple and S padded to
     the q/kv tile (zero rows/cols); lse and delta ( = rowsum(dO * O), fp32 )
-    are (N, H, Sq) padded with zeros. Zero-padded dO rows make every padded
+    are (N, H, Sq) padded with zeros; mask is (N, 1, Skv) like the
+    forward's. Zero-padded dO rows make every padded
     contribution vanish (ds = p * (dp - delta) = 0), and padded KV columns
     are re-masked to NEG_INF in-kernel exactly as in the forward.
 
@@ -520,10 +540,10 @@ def flash_attention_bwd_pallas(
                          lambda *g: (g[0], g[1], jk_of(g), 0)),
             pl.BlockSpec((1, 1, q_tile, d),
                          lambda *g: (g[0], g[1], iq_of(g), 0)),
-            pl.BlockSpec((1, 1, q_tile),
-                         lambda *g: (g[0], g[1], iq_of(g))),
-            pl.BlockSpec((1, 1, q_tile),
-                         lambda *g: (g[0], g[1], iq_of(g))),
+            pl.BlockSpec((1, 1, 1, q_tile),
+                         lambda *g: (g[0], g[1], 0, iq_of(g))),
+            pl.BlockSpec((1, 1, 1, q_tile),
+                         lambda *g: (g[0], g[1], 0, iq_of(g))),
         ]
 
     rep = 1
@@ -536,7 +556,8 @@ def flash_attention_bwd_pallas(
     # entirely (3 recompute sweeps -> 2).
     fuse_dbias = has_bias and rep == 1
 
-    base_ops = [q, k, v, do, lse, delta]
+    base_ops = [q, k, v, do, lse.reshape(n, h, 1, sq),
+                delta.reshape(n, h, 1, sq)]
 
     # --- sweep 1: dq (+ dbias when the bias group is mesh-local),
     #     grid (N, H, nq, nkv), KV innermost ---
@@ -548,9 +569,9 @@ def flash_attention_bwd_pallas(
             lambda i, j, iq, jk: (i // rep, j, iq, jk)))
         operands.append(bias)
     if has_mask:
-        assert mask is not None and mask.shape == (n, skv)
-        in_specs.append(pl.BlockSpec((1, kv_tile),
-                                     lambda i, j, iq, jk: (i, jk)))
+        assert mask is not None and mask.shape == (n, 1, skv)
+        in_specs.append(pl.BlockSpec((1, 1, kv_tile),
+                                     lambda i, j, iq, jk: (i, 0, jk)))
         operands.append(mask)
     out_specs = [pl.BlockSpec((1, 1, q_tile, d),
                               lambda i, j, iq, jk: (i, j, iq, 0))]
@@ -582,8 +603,8 @@ def flash_attention_bwd_pallas(
             lambda i, j, jk, iq: (i // rep, j, iq, jk)))
         operands.append(bias)
     if has_mask:
-        in_specs.append(pl.BlockSpec((1, kv_tile),
-                                     lambda i, j, jk, iq: (i, jk)))
+        in_specs.append(pl.BlockSpec((1, 1, kv_tile),
+                                     lambda i, j, jk, iq: (i, 0, jk)))
         operands.append(mask)
     kv_spec = pl.BlockSpec((1, 1, kv_tile, d),
                            lambda i, j, jk, iq: (i, j, jk, 0))
@@ -593,9 +614,9 @@ def flash_attention_bwd_pallas(
     scratch = [pltpu.VMEM((kv_tile, d), jnp.float32),
                pltpu.VMEM((kv_tile, d), jnp.float32)]
     if has_mask:
-        out_specs.append(pl.BlockSpec((1, 1, kv_tile),
-                                      lambda i, j, jk, iq: (i, j, jk)))
-        out_shape.append(jax.ShapeDtypeStruct((n, h, skv), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, 1, kv_tile),
+                                      lambda i, j, jk, iq: (i, j, 0, jk)))
+        out_shape.append(jax.ShapeDtypeStruct((n, h, 1, skv), jnp.float32))
         scratch.append(pltpu.VMEM((8, kv_tile), jnp.float32))
     outs = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, kv_len=kv_len,
@@ -609,7 +630,7 @@ def flash_attention_bwd_pallas(
         interpret=interpret,
     )(*operands)
     dk, dv = outs[0], outs[1]
-    dmask_h = outs[2] if has_mask else None
+    dmask_h = outs[2].reshape(n, h, skv) if has_mask else None
 
     # --- sweep 3: dbias, grid (B, H, nq, nkv, rep), bias group innermost.
     #     Skipped when the dq sweep already emitted dbias (rep == 1). ---
@@ -627,17 +648,18 @@ def flash_attention_bwd_pallas(
                          lambda b, j, iq, jk, r: (b * rep + r, j, jk, 0)),
             pl.BlockSpec((1, 1, q_tile, d),
                          lambda b, j, iq, jk, r: (b * rep + r, j, iq, 0)),
-            pl.BlockSpec((1, 1, q_tile),
-                         lambda b, j, iq, jk, r: (b * rep + r, j, iq)),
-            pl.BlockSpec((1, 1, q_tile),
-                         lambda b, j, iq, jk, r: (b * rep + r, j, iq)),
+            pl.BlockSpec((1, 1, 1, q_tile),
+                         lambda b, j, iq, jk, r: (b * rep + r, j, 0, iq)),
+            pl.BlockSpec((1, 1, 1, q_tile),
+                         lambda b, j, iq, jk, r: (b * rep + r, j, 0, iq)),
             pl.BlockSpec((1, 1, q_tile, kv_tile),
                          lambda b, j, iq, jk, r: (b, j, iq, jk)),
         ]
         operands = list(base_ops) + [bias]
         if has_mask:
             in_specs.append(pl.BlockSpec(
-                (1, kv_tile), lambda b, j, iq, jk, r: (b * rep + r, jk)))
+                (1, 1, kv_tile),
+                lambda b, j, iq, jk, r: (b * rep + r, 0, jk)))
             operands.append(mask)
         dbias = pl.pallas_call(
             functools.partial(_bwd_dbias_kernel, scale=scale, kv_len=kv_len,

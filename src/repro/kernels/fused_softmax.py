@@ -40,7 +40,7 @@ def _softmax_kernel(*refs, scale: float, c_actual: int, has_bias: bool, has_mask
     if b_ref is not None:
         x = x + b_ref[0, 0].astype(jnp.float32)
     if m_ref is not None:
-        x = x + m_ref[0].astype(jnp.float32)[None, :]
+        x = x + m_ref[0].astype(jnp.float32)      # (1, C_pad) row
     # Neutralize lane padding (C_pad > C): padded lanes must not win the max
     # nor contribute to the sum.
     if c_actual != x.shape[-1]:
@@ -87,8 +87,11 @@ def fused_softmax_pallas(
         operands.append(bias)
     if has_mask:
         assert mask is not None and mask.shape == (n, c)
-        in_specs.append(pl.BlockSpec((1, c_pad), lambda i, j, k: (i, 0)))
-        operands.append(mask)
+        # (N, 1, C): a (1, 1, C_pad) block keeps its second-minor dim equal
+        # to the array's — the TPU (8, 128) block rule refuses (1, C_pad).
+        in_specs.append(pl.BlockSpec((1, 1, c_pad),
+                                     lambda i, j, k: (i, 0, 0)))
+        operands.append(mask.reshape(n, 1, c))
 
     kernel = functools.partial(
         _softmax_kernel,

@@ -50,6 +50,12 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+# Row-wise op families called on whole (possibly mesh-sharded) arrays. The
+# attention/triangle/OPM kernels reach GSPMD only inside the dist backend's
+# shard_map; these do not, and GSPMD cannot partition a Mosaic call.
+_ROW_OPS = ("softmax", "layer_norm", "elementwise")
+
+
 def kernel_leg(op: str) -> str:
     """Resolved execution leg for an op family under the current plan:
     'pallas' | 'interpret' | 'xla' | 'oracle'. An explicit per-op leg on
@@ -58,14 +64,19 @@ def kernel_leg(op: str) -> str:
     (a per-grid-cell loop) only under ``KernelPolicy.interpret`` (the
     kernel-validation CI leg), which is both faster on CPU and safe to lower
     inside large SPMD dry-runs. ``enabled=False`` sends every 'auto' op to
-    its jnp oracle."""
-    pol = current_plan().kernels
+    its jnp oracle. Under the 'gspmd' parallel backend the row-wise families
+    (``_ROW_OPS``) resolve to their XLA leg on TPU too: their arrays are
+    global there, and XLA fuses and partitions the row math itself."""
+    plan = current_plan()
+    pol = plan.kernels
     leg = getattr(pol, op)
     if leg != "auto":
         return leg
     if not pol.enabled:
         return "oracle"
     if jax.default_backend() == "tpu":
+        if op in _ROW_OPS and plan.parallel.backend == "gspmd":
+            return "xla"
         return "pallas"
     return "interpret" if pol.interpret else "xla"
 
@@ -262,7 +273,7 @@ def _attn_stage_padded(kv_tile, q, k, v, bias, mask):
                             (0, skv_pad - skv)))
     mt = None
     if mask is not None:
-        mt = jnp.pad(mask, ((0, 0), (0, skv_pad - skv)))
+        mt = jnp.pad(mask, ((0, 0), (0, skv_pad - skv)))[:, None, :]
     return qt, kt, vt, bt, mt, q_tile, kv_t, sq_pad, skv_pad
 
 
@@ -487,16 +498,17 @@ def fused_attention(
 # fused triangle multiplicative update + outer-product-mean (pair stack)
 # ---------------------------------------------------------------------------
 
-# Envelope: the tile-epilogue GEMMs keep (i_t*j_t, C) and (i_t*j_t, C*C)
-# operands in VMEM — bound C (triangle channel) and C_opm². The OPM bound is
-# set by the (i_t·C, j_t·C) fp32 accumulator + (C², D) weight block fitting
-# ~16 MB VMEM (c=64 → 4 MB + 2 MB at i_t=j_t=16; c=128 would need 24 MB).
+# Envelope: the triangle epilogue keeps (i_t*j_t, C) operands in VMEM —
+# bound C (triangle channel). The OPM bound on its channel is set by its
+# (C, i_t·C, j_t) fp32 accumulator fitting the kernels' scoped VMEM
+# (triangle.VMEM_LIMIT_BYTES): c=32 → 4 MiB, c=64 → 16 MiB at i_t=8,
+# j_t=128; c=128 would need 64 MiB.
 _MAX_TRI_C = 1024
 _MAX_OPM_C = 64
 # Default j output block of the XLA legs and the backward recompute scans
 # (the HBM-visible transient the AutoChunk planner models). The Pallas
-# kernels' internal accumulation tile default is smaller (VMEM-budgeted):
-# kernels/triangle.py DEFAULT_PALLAS_TILE.
+# kernels' k/s accumulation tile default is kernels/triangle.py
+# DEFAULT_PALLAS_TILE.
 _DEFAULT_TRI_TILE = 128
 _DEFAULT_OPM_TILE = 128
 
@@ -599,7 +611,7 @@ def fused_triangle_mult(
     mesh-sharded go through ``dist.sharded_triangle`` so the kernel sees
     local blocks); gamma/beta (C,); w_out (C, D); b_out/g_bias (D,);
     g_lin (B, I, J, D). ``tile`` is the Pallas k tile / XLA j block /
-    backward recompute block (0 = leg default: Pallas 64, XLA/backward
+    backward recompute block (0 = leg default: Pallas 128, XLA/backward
     128) — AutoChunk plans it as ``tri_k_tile``.
 
     custom_vjp: forward saves inputs + per-tile (mean, inv) LN stats; the
@@ -667,7 +679,7 @@ def fused_outer_product_mean(
     gathered under DAP — mesh-sharded I goes through ``dist.sharded_opm``);
     mask_a (B, S, I), mask_b (B, S, J); w (C*C, D), bias (D,). ``tile`` is
     the Pallas s tile / XLA j block / backward recompute block (0 = leg
-    default: Pallas 64, XLA/backward 128) — AutoChunk plans it as
+    default: Pallas 128, XLA/backward 128) — AutoChunk plans it as
     ``opm_s_tile``.
 
     custom_vjp: forward saves only the inputs (the mask-norm is recomputed);

@@ -23,10 +23,11 @@ Three legs per op (selected by ``ops.fused_triangle_mult`` /
   GEMM, and the ``bias_sigmoid_mul`` output gate before the single HBM write
   of the ``(i_t, j_t, D)`` result — plus the per-tile (mean, inv) stats the
   recompute backward reuses. OPM: grid ``(B, I/i_t, J/j_t, S/s_t)`` with the
-  sequence (s) innermost, accumulating the ``(i_t·C, j_t·C)`` fp32 outer
-  product and the ``(i_t, j_t)`` mask-norm in scratch; the epilogue divides
-  by the fp32 mask normalization and contracts c² → d in VMEM, so the
-  ``(B, i, j, c, c)`` transient exists only as one tile.
+  sequence (s) innermost, accumulating the fp32 outer product in scratch as
+  C slabs of ``(i_t·C, j_t)`` (one per right channel, so every VMEM access
+  is row-aligned); the epilogue contracts c² → d in VMEM and divides by the
+  fp32 mask normalization, so the ``(B, i, j, c, c)`` transient exists only
+  as one tile.
 
 * **XLA-native leg** (``fused_triangle_xla`` / ``fused_opm_xla``) — non-TPU
   backends (mirrors ``flash_attention_xla``): a ``lax.scan`` over j output
@@ -63,13 +64,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.flash_attention import KERNEL_PRECISION
+
 LANE = 128
 OPM_NORM_EPS = 1e-3  # AlphaFold's outer-product-mean mask-norm epsilon
-# Default k/s accumulation tile of the Pallas grids when the knob is 0 —
-# VMEM-budgeted, deliberately smaller than the XLA legs' default j block
-# (ops._DEFAULT_TRI_TILE / _DEFAULT_OPM_TILE = 128, the HBM-visible
-# transient the AutoChunk planner models).
-DEFAULT_PALLAS_TILE = 64
+# Default k/s accumulation tile of the Pallas grids when the knob is 0. The
+# triangle kernel's k tile sits in the lane (minor) position of its mask
+# block, so on TPU it is a multiple of 128 unless one tile covers all of K
+# (``_lane_tile``).
+DEFAULT_PALLAS_TILE = 128
+# Scoped-VMEM limit for the pair-stack kernels. Their double-buffered
+# (j_t, k_t, C) operand tiles exceed the 16 MiB default at C = 128; a v5e
+# core has 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 64 << 20
 
 
 def _pad_to(n: int, m: int) -> int:
@@ -78,6 +85,15 @@ def _pad_to(n: int, m: int) -> int:
 
 def _ceil_div(a: int, b: int) -> int:
     return -(-a // b)
+
+
+def _lane_tile(n: int, tile: int) -> int:
+    """Tile of a dim that is the minor (lane) dim of some block: the TPU
+    lowering needs it to be a multiple of 128 or the whole (padded) extent."""
+    full = _pad_to(n, 8)
+    if tile >= full:
+        return full
+    return min(_pad_to(tile, LANE), _pad_to(n, LANE))
 
 
 def triangle_gate_a(a_lin, ga, mask):
@@ -107,14 +123,17 @@ def _tri_kernel(a_ref, ga_ref, mk_ref, b_ref, gam_ref, bet_ref, w_ref,
 
     # Input gating + pair mask fused in VMEM (the gated a never hits HBM).
     a = (a_ref[0].astype(jnp.float32)
-         * jax.nn.sigmoid(ga_ref[0].astype(jnp.float32)))
-    a = a.astype(a_ref.dtype) * mk_ref[0].astype(a_ref.dtype)[..., None]
+         * jax.nn.sigmoid(ga_ref[0].astype(jnp.float32))).astype(a_ref.dtype)
+    # Channel-major (C, i_t, k_t): the (i_t, k_t) mask tile then broadcasts
+    # over the leading dim, with no lane-to-sublane relayout of the mask.
+    a = a.transpose(2, 0, 1) * mk_ref[0].astype(a_ref.dtype)[None]
     b = b_ref[0]                                   # (j_t, k_t, C)
     # o[c, i, j] += sum_k a[i, k, c] * b[j, k, c]: batch over c, contract k.
     acc_ref[...] += jax.lax.dot_general(
-        a.transpose(2, 0, 1), b.transpose(2, 0, 1),
+        a, b.transpose(2, 0, 1),
         (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
+        precision=KERNEL_PRECISION,
     )
 
     @pl.when(kk == n_k - 1)
@@ -136,6 +155,7 @@ def _tri_kernel(a_ref, ga_ref, mk_ref, b_ref, gam_ref, bet_ref, w_ref,
         z = jax.lax.dot_general(
             y, w_ref[...].astype(y.dtype), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=KERNEL_PRECISION,
         ) + bo_ref[...][0].astype(jnp.float32)
         gl = (gl_ref[0].reshape(i_t * j_t, -1).astype(jnp.float32)
               + gb_ref[...][0].astype(jnp.float32))
@@ -173,7 +193,7 @@ def fused_triangle_pallas(
 
     i_t = min(16, _pad_to(i_len, 8))
     j_t = min(128, _pad_to(j_len, 8))
-    k_t = min(_pad_to(k_tile or DEFAULT_PALLAS_TILE, 8), _pad_to(k_len, 8))
+    k_t = _lane_tile(k_len, k_tile or DEFAULT_PALLAS_TILE)
     ip, jp, kp = _pad_to(i_len, i_t), _pad_to(j_len, j_t), _pad_to(k_len, k_t)
     cp, dp = _pad_to(c, LANE), _pad_to(d, LANE)
 
@@ -219,6 +239,8 @@ def fused_triangle_pallas(
             jax.ShapeDtypeStruct((bsz, ip, jp), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((cp, i_t, j_t), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(a_p, ga_p, mk_p, b_p, gam_p, bet_p, w_p, bo_p, gl_p, gb_p)
     return (out[:, :i_len, :j_len, :d], mean[:, :i_len, :j_len],
@@ -379,45 +401,53 @@ def triangle_mult_bwd(eps: float, tile: int, res, dout):
 # ---------------------------------------------------------------------------
 
 
-def _opm_kernel(a_ref, b_ref, ma_ref, mb_ref, w_ref, bias_ref, o_ref,
-                acc_ref, nrm_ref, *, c: int):
+def _opm_kernel(at_ref, b_ref, nrm_ref, wt_ref, bias_ref, o_ref, acc_ref,
+                zt_ref, *, i_t: int, c: int):
     ss = pl.program_id(3)
     n_s = pl.num_programs(3)
 
     @pl.when(ss == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        nrm_ref[...] = jnp.zeros_like(nrm_ref)
 
-    a = a_ref[0]                                    # (s_t, i_t*C)
-    b = b_ref[0]                                    # (s_t, j_t*C)
-    acc_ref[...] += jax.lax.dot_general(
-        a, b, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                               # (i_t*C, j_t*C)
-    ma = ma_ref[0].astype(jnp.float32)              # (s_t, i_t)
-    mb = mb_ref[0].astype(jnp.float32)              # (s_t, j_t)
-    j_t = mb.shape[-1]
-    nrm_ref[:, :j_t] += jax.lax.dot_general(
-        ma, mb, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    a_t = at_ref[0]                                 # (i_t*C, s_t), rows (i, x)
+
+    # acc[y][(i, x), j] += sum_s a[s, i, x] * b[s, j, y]: one MXU GEMM per
+    # right channel y, each with the whole i-tile's channels as rows.
+    def accumulate(y, carry):
+        acc_ref[y] += jax.lax.dot_general(
+            a_t, b_ref[0, y], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=KERNEL_PRECISION)
+        return carry
+
+    jax.lax.fori_loop(0, c, accumulate, 0)
 
     @pl.when(ss == n_s - 1)
     def _epilogue():
-        o = acc_ref[...]
-        i_t = o.shape[0] // c
-        j_t = o.shape[1] // c
-        # (i_t*C, j_t*C) -> (i_t*j_t, C*C) vectorized outer products.
-        o4 = o.reshape(i_t, c, j_t, c).transpose(0, 2, 1, 3)
-        o2 = o4.reshape(i_t * j_t, c * c)
-        norm = nrm_ref[:, :j_t].reshape(i_t * j_t, 1)
-        ov = (o2 / (norm + OPM_NORM_EPS)).astype(o_ref.dtype)
-        z = jax.lax.dot_general(
-            ov, w_ref[...].astype(ov.dtype), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) + bias_ref[...][0].astype(jnp.float32)
-        o_ref[0] = z.reshape(i_t, j_t, -1).astype(o_ref.dtype)
+        # c²→d projection, transposed: zt[i][d, j] = sum_{x, y} W[x, y, d]
+        # * acc[y][(i, x), j]. Every operand is a row-aligned slice, so the
+        # (i, j, x, y) outer-product tile is never re-laid out in VMEM.
+        zt_ref[...] = jnp.zeros_like(zt_ref)
+
+        def project(y, carry):
+            w_y = wt_ref[y]                         # (D, C_x)
+            for i in range(i_t):
+                slab = acc_ref[y, pl.ds(i * c, c), :].astype(w_y.dtype)
+                zt_ref[i] += jax.lax.dot_general(
+                    w_y, slab, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=KERNEL_PRECISION)
+            return carry
+
+        jax.lax.fori_loop(0, c, project, 0)
+        nrm = nrm_ref[0]                            # (i_t, j_t) fp32
+        bias = bias_ref[...].astype(jnp.float32)    # (1, D)
+        for i in range(i_t):
+            # The mask-norm is a per-(i, j) scalar and the projection linear,
+            # so dividing after the projection is the same normalization.
+            z = zt_ref[i] / (nrm[i:i + 1, :] + OPM_NORM_EPS)   # (D, j_t)
+            o_ref[0, i] = (z.T + bias).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("s_tile", "interpret"))
@@ -433,52 +463,59 @@ def fused_opm_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     """Fused outer-product-mean (see module docstring). Returns
-    (B, I, J, D) in a.dtype."""
+    (B, I, J, D) in a.dtype.
+
+    Layouts staged here for the TPU (8, 128) block rule: the left operand
+    as (B, I·C, S) (s in lanes, so the s tile is a lane tile), the right as
+    (B, C, S, J), the projection as (C_y, D, C_x) and the (B, I, J) fp32
+    mask-norm computed up front (it is an (I, J, S) contraction of 0/1
+    masks — negligible next to the c² product)."""
     bsz, s_len, i_len, c = a.shape
     j_len = b.shape[2]
     d = w.shape[1]
     dt = a.dtype
 
-    i_t = min(16, _pad_to(i_len, 8))
-    j_t = min(16, _pad_to(j_len, 8))
-    s_t = min(_pad_to(s_tile or DEFAULT_PALLAS_TILE, 8), _pad_to(s_len, 8))
-    ip, jp = _pad_to(i_len, i_t), _pad_to(j_len, j_t)
-    sp = _pad_to(s_len, s_t)
+    cp = _pad_to(c, 8)
+    i_t = min(8, _pad_to(i_len, 8))
+    j_t = min(LANE, _pad_to(j_len, 8))
+    s_t = _lane_tile(s_len, s_tile or DEFAULT_PALLAS_TILE)
+    ip, jp, sp = _pad_to(i_len, i_t), _pad_to(j_len, j_t), _pad_to(s_len, s_t)
     dp = _pad_to(d, LANE)
 
-    def pad_proj(x, n_r):
-        xp = jnp.pad(x, ((0, 0), (0, sp - s_len), (0, n_r - x.shape[2]),
-                         (0, 0)))
-        return xp.reshape(bsz, sp, n_r * c)        # free reshape, lane-merged
-
-    a_p = pad_proj(a, ip)
-    b_p = pad_proj(b, jp)
-    ma_p = jnp.pad(mask_a, ((0, 0), (0, sp - s_len), (0, ip - i_len)))
-    mb_p = jnp.pad(mask_b, ((0, 0), (0, sp - s_len), (0, jp - j_len)))
-    w_p = jnp.pad(w, ((0, 0), (0, dp - d)))
+    a_t = jnp.pad(a, ((0, 0), (0, sp - s_len), (0, ip - i_len),
+                      (0, cp - c)))
+    a_t = a_t.transpose(0, 2, 3, 1).reshape(bsz, ip * cp, sp)
+    b_t = jnp.pad(b, ((0, 0), (0, sp - s_len), (0, jp - j_len),
+                      (0, cp - c))).transpose(0, 3, 1, 2)
+    f32 = jnp.float32
+    nrm = jnp.einsum("bsi,bsj->bij", mask_a.astype(f32), mask_b.astype(f32))
+    nrm = jnp.pad(nrm, ((0, 0), (0, ip - i_len), (0, jp - j_len)))
+    w3 = jnp.pad(w.reshape(c, c, d), ((0, cp - c), (0, cp - c), (0, dp - d)))
+    w_t = w3.transpose(1, 2, 0).astype(dt)          # (C_y, D, C_x)
     bias_p = jnp.pad(bias, (0, dp - d)).reshape(1, dp)
 
     grid = (bsz, ip // i_t, jp // j_t, sp // s_t)
     out = pl.pallas_call(
-        functools.partial(_opm_kernel, c=c),
+        functools.partial(_opm_kernel, i_t=i_t, c=cp),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, s_t, i_t * c), lambda b_, i, j, s: (b_, s, i)),
-            pl.BlockSpec((1, s_t, j_t * c), lambda b_, i, j, s: (b_, s, j)),
-            pl.BlockSpec((1, s_t, i_t), lambda b_, i, j, s: (b_, s, i)),
-            pl.BlockSpec((1, s_t, j_t), lambda b_, i, j, s: (b_, s, j)),
-            pl.BlockSpec((c * c, dp), lambda b_, i, j, s: (0, 0)),
+            pl.BlockSpec((1, i_t * cp, s_t), lambda b_, i, j, s: (b_, i, s)),
+            pl.BlockSpec((1, cp, s_t, j_t), lambda b_, i, j, s: (b_, 0, s, j)),
+            pl.BlockSpec((1, i_t, j_t), lambda b_, i, j, s: (b_, i, j)),
+            pl.BlockSpec((cp, dp, cp), lambda b_, i, j, s: (0, 0, 0)),
             pl.BlockSpec((1, dp), lambda b_, i, j, s: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, i_t, j_t, dp),
                                lambda b_, i, j, s: (b_, i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, ip, jp, dp), dt),
         scratch_shapes=[
-            pltpu.VMEM((i_t * c, j_t * c), jnp.float32),
-            pltpu.VMEM((i_t, max(j_t, LANE)), jnp.float32),
+            pltpu.VMEM((cp, i_t * cp, j_t), jnp.float32),
+            pltpu.VMEM((i_t, dp, j_t), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(a_p, b_p, ma_p, mb_p, w_p, bias_p)
+    )(a_t, b_t, nrm, w_t, bias_p)
     return out[:, :i_len, :j_len, :d]
 
 

@@ -41,6 +41,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import INPUT_SHAPES, get_config, list_archs
 from repro.configs.base import ModelConfig, ShapeConfig
+from repro.launch.cache import enable_compilation_cache
 from repro.launch.mesh import (
     HBM_BW, HBM_BYTES, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh,
 )
@@ -284,8 +285,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         donate = (2,)
     else:
         donate = ()
-    # jax>=0.5 wants jax.set_mesh; older jax uses the Mesh context manager.
-    with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                          donate_argnums=donate)
         lowered = jitted.lower(*args)
@@ -293,9 +293,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    # jax 0.4.x returns a one-element list of cost dicts; >=0.5 a plain dict.
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     flops, hbm_bytes = analysis.hlo_cost(hlo)
     coll = analysis.parse_collectives(hlo, mesh.shape["model"])
@@ -343,6 +340,7 @@ def main():
     ap.add_argument("--include-alphafold", action="store_true")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compilation_cache()
 
     jobs = []
     if args.all:

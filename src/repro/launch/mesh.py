@@ -9,16 +9,10 @@ import jax
 
 
 def _mesh(shape, axes):
-    """jax.make_mesh across jax versions: axis_types (Auto) when the running
-    jax supports it, plain mesh otherwise (pre-0.5 jax has no AxisType and
-    defaults to the same auto behavior)."""
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (GSPMD propagates shardings;
+    the DAP code pins them with ``with_sharding_constraint``)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.configs import get_config, list_archs
 from repro.exec.plan import PRESETS, preset
+from repro.launch.cache import enable_compilation_cache
 from repro.models.decoder import init_model
 from repro.serving.engine import ServingEngine
 
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--plan", default="default", choices=sorted(PRESETS),
                     help="ExecutionPlan preset the engine binds")
     args = ap.parse_args()
+    enable_compilation_cache()
 
     cfg = get_config(args.arch, reduced_variant=args.reduced)
     params = init_model(jax.random.PRNGKey(0), cfg)

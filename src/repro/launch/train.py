@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.configs import get_config, list_archs
 from repro.data import lm_batches
 from repro.exec.plan import PRESETS, preset, use_plan
+from repro.launch.cache import enable_compilation_cache
 from repro.layers.params import count_params
 from repro.models.decoder import init_model, lm_loss
 from repro.train.checkpoint import save_checkpoint
@@ -42,6 +43,7 @@ def main():
                     help="record obs train_step telemetry to this JSONL "
                          "file (inspect with `python -m repro.obs report`)")
     args = ap.parse_args()
+    enable_compilation_cache()
 
     with use_plan(preset(args.plan)):
         if args.trace:

@@ -340,7 +340,7 @@ def naive(x):
         x * 2.0, NamedSharding(mesh, P(None, None, None)))
     return y + 1.0
 
-with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+with jax.set_mesh(mesh):
     hlo = jax.jit(naive).lower(x).compile().as_text()
 bad = find_merged_allgathers(hlo, {B * G}, min_rank=3)
 assert bad, "expected the naive flatten-then-shard to force a merged-lead " \

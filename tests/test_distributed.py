@@ -95,7 +95,7 @@ mesh = _mesh((2, 2), ("data", "model"))
 def shard_x(x):
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, P("data", "model", None)))
-with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+with jax.set_mesh(mesh):
     loss_sharded, _ = jax.jit(
         lambda p, b: lm_loss(p, b, cfg, shard_x=shard_x))(params, batch)
 np.testing.assert_allclose(float(loss_sharded), float(loss_ref), rtol=1e-4)
@@ -113,7 +113,7 @@ mesh = _mesh((2, 4), ("data", "model"))
 cfg = get_config("qwen2-1.5b", reduced_variant=True)
 shape = dataclasses.replace(INPUT_SHAPES["train_4k"], seq_len=64, global_batch=4)
 fn, args, in_sh, out_sh = dr.build_train(cfg, shape, mesh)
-with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+with jax.set_mesh(mesh):
     compiled = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh).lower(*args).compile()
 mem = compiled.memory_analysis()
 assert mem is not None
@@ -178,7 +178,7 @@ def counting(self, *a, **kw):
     return orig(self, *a, **kw)
 GspmdDist.sharded_attention = counting
 dist = GspmdDist(mesh=mesh, axis="model")
-with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+with jax.set_mesh(mesh):
     fwd = jax.jit(lambda p: evoformer_stack(p, msa, pair, *masks, dist=dist,
                                             cfg=cfg, remat=False))
     m, z = fwd(params)
@@ -209,7 +209,7 @@ TRIANGLE_DIST_SCRIPT = r"""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.core.dist import (GspmdDist, LocalDist, ShardMapDist,
-                             shard_map_compat)
+                             unchecked_shard_map)
 from repro.core.evoformer import EvoformerConfig, init_evoformer_stack, \
     evoformer_stack
 from repro.kernels import ops
@@ -254,7 +254,7 @@ mesh = _mesh((1, n_dev), ("data", "model"))
 
 # ---- GspmdDist: shard-mapped fused pair-stack ops, fwd + grad + HLO ----
 dist = GspmdDist(mesh=mesh, axis="model")
-with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+with jax.set_mesh(mesh):
     fwd_tri = jax.jit(lambda a, b: dist.sharded_triangle(
         a, *targs[1:3], b, *targs[4:], tile=4))
     close(fwd_tri(a_lin, b_full), tri_ref, "gspmd tri fwd")
@@ -287,12 +287,12 @@ print("GSPMD_TRI_OK", n_dev)
 smd = ShardMapDist(axis="model")
 row4 = P(None, "model", None, None)
 rep = lambda x: P(*([None] * x.ndim))
-tri_sm = shard_map_compat(
+tri_sm = unchecked_shard_map(
     lambda a, g_, mk, bf, gl: smd.sharded_triangle(
         a, g_, mk, bf, gamma, beta, w_out, b_out, gl, g_bias, tile=4),
     mesh, (row4, row4, P(None, "model", None), rep(b_full), row4), row4)
 close(jax.jit(tri_sm)(a_lin, ga, mask, b_full, g_lin), tri_ref, "smd tri")
-opm_sm = shard_map_compat(
+opm_sm = unchecked_shard_map(
     lambda a, bf, ma, mb: smd.sharded_opm(a, bf, ma, mb, ow, obias, tile=4),
     mesh, (P(None, None, "model", None), rep(ob), P(None, None, "model"),
            rep(omb)), row4)
@@ -321,7 +321,7 @@ masks = (jnp.ones((B2, s, r)), jnp.ones((B2, r)), jnp.ones((B2, r, r)))
 m_ref, z_ref = evoformer_stack(params, msa, pair, *masks, cfg=cfg,
                                remat=False)
 dist2 = GspmdDist(mesh=mesh, axis="model")
-with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+with jax.set_mesh(mesh):
     m, z = jax.jit(lambda p: evoformer_stack(
         p, msa, pair, *masks, dist=dist2, cfg=cfg, remat=False))(params)
 close(m, m_ref, "evo msa"); close(z, z_ref, "evo pair")

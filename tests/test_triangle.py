@@ -26,6 +26,14 @@ ATOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 # rounds the normalized outer product to the compute dtype first — in bf16
 # the A/B delta is the oracle's own rounding, so the OPM bound is wider.
 OPM_ATOL = {jnp.float32: 2e-5, jnp.bfloat16: 5e-2}
+# fp32 rounding bounds for the OPM checks against a float64 reference, in
+# ulps of the checked array's largest magnitude: its outputs reach ~25 and
+# its gradients ~1e4 (the (norm + 1e-3) divisor of an all-masked (i, j) pair
+# scales its a/b cotangents by 1e3), so an absolute bound tight enough for
+# O(1) values sits below one ulp there.
+EPS32 = float(np.finfo(np.float32).eps)
+OPM_FWD_ULPS = 8
+OPM_GRAD_ULPS = 64
 
 
 def _tri_inputs(dtype, mask_mode, B=2, I=5, J=7, K=6, C=16, D=12, seed=0):
@@ -108,12 +116,48 @@ def test_triangle_tile_invariance():
                                    atol=1e-6)
 
 
+def _opm_sin_grads_f64(a, b, mask_a, mask_b, w, bias):
+    """Float64 numpy OPM forward and the gradients of ``sum(sin(out))``
+    for every input — the reference both fused and oracle legs round
+    away from."""
+    a, b, ma, mb, w, bias = (np.asarray(x, np.float64)
+                             for x in (a, b, mask_a, mask_b, w, bias))
+    bsz, _, i, c = a.shape
+    j = b.shape[2]
+    o = np.einsum("bsix,bsjy->bijxy", a, b).reshape(bsz, i, j, c * c)
+    denom = np.einsum("bsi,bsj->bij", ma, mb) + 1e-3
+    ov = o / denom[..., None]
+    out = ov @ w + bias
+    g = np.cos(out)
+    dov = g @ w.T
+    dden = -np.einsum("bijk,bijk->bij", ov, dov) / denom
+    do = (dov / denom[..., None]).reshape(bsz, i, j, c, c)
+    grads = (np.einsum("bijxy,bsjy->bsix", do, b),
+             np.einsum("bijxy,bsix->bsjy", do, a),
+             np.einsum("bij,bsj->bsi", dden, mb),
+             np.einsum("bij,bsi->bsj", dden, ma),
+             np.einsum("bijk,bijd->kd", ov, g),
+             g.sum(axis=(0, 1, 2)))
+    return out, grads
+
+
+def _assert_within_ulps(got, want, ulps, what):
+    scale = max(1.0, float(np.abs(want).max()))
+    err = float(np.abs(np.asarray(got, np.float64) - want).max())
+    assert err <= ulps * EPS32 * scale, (what, err, ulps * EPS32 * scale)
+
+
 def test_opm_tile_invariance():
+    """The tile is a pure execution knob: every tile lands within fp32
+    rounding of the float64 result, and of each other. Not bit-identical —
+    XLA blocks the j-slab dots differently per width, reordering the sums."""
     args = _opm_inputs(jnp.float32, "sparse", seed=3)
-    outs = [ops.fused_outer_product_mean(*args, tile=t) for t in (0, 2, 3, 8)]
-    for o in outs[1:]:
-        np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o),
-                                   atol=1e-6)
+    want, _ = _opm_sin_grads_f64(*args)
+    outs = [np.asarray(ops.fused_outer_product_mean(*args, tile=t))
+            for t in (0, 2, 3, 8)]
+    for t, o in zip((0, 2, 3, 8), outs):
+        _assert_within_ulps(o, want, OPM_FWD_ULPS, f"tile {t}")
+        _assert_within_ulps(o, outs[0], 2 * OPM_FWD_ULPS, f"tile {t} vs 0")
 
 
 # ---------------------------------------------------------------------------
@@ -145,6 +189,10 @@ def test_triangle_grad_parity(mask_mode, tile):
 @pytest.mark.parametrize("mask_mode", ["ones", "sparse"])
 @pytest.mark.parametrize("tile", [0, 3])
 def test_opm_grad_parity(mask_mode, tile):
+    """jax.grad through the recompute custom_vjp and autodiff of the
+    materialized oracle both land within fp32 rounding of the float64
+    gradient, for every input. The mask cotangents sum terms of ~1e2 into
+    values near zero, so a bound relative to each element cannot hold."""
     args = _opm_inputs(jnp.float32, mask_mode, seed=5)
     n = len(args)
 
@@ -154,11 +202,12 @@ def test_opm_grad_parity(mask_mode, tile):
     def f2(*a):
         return jnp.sum(jnp.sin(ref.outer_product_mean_ref(*a)))
 
+    _, want = _opm_sin_grads_f64(*args)
     g1 = jax.grad(f1, argnums=tuple(range(n)))(*args)
     g2 = jax.grad(f2, argnums=tuple(range(n)))(*args)
-    for a, b in zip(g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5,
-                                   rtol=1e-3)
+    for k, (a, b, w) in enumerate(zip(g1, g2, want)):
+        _assert_within_ulps(a, w, OPM_GRAD_ULPS, f"fused d{k}")
+        _assert_within_ulps(b, w, OPM_GRAD_ULPS, f"oracle d{k}")
 
 
 def test_triangle_grad_parity_bf16():
@@ -206,6 +255,29 @@ def test_opm_xla_leg_matches_pallas_interpret(monkeypatch):
     y_pallas = ops.fused_outer_product_mean(*args, tile=4)
     np.testing.assert_allclose(np.asarray(y_xla), np.asarray(y_pallas),
                                atol=2e-5)
+
+
+@pytest.mark.parametrize("op", ["triangle", "opm"])
+def test_pallas_multi_tile_parity(op):
+    """Interpret-mode kernels at shapes spanning several tiles on every grid
+    axis, with padding on each (triangle: 3 i x 2 j x 3 k tiles; OPM: 3 i x
+    2 j x 3 s tiles) — the accumulator carry and edge tiles the small
+    shapes above never reach."""
+    with use_plan(preset("interpret")):
+        if op == "triangle":
+            args = _tri_inputs(jnp.float32, "sparse", B=1, I=40, J=200,
+                               K=300, seed=4)
+            got = ops.fused_triangle_mult(*args)
+        else:
+            args = _opm_inputs(jnp.float32, "sparse", B=1, S=300, I=20,
+                               J=200, seed=4)
+            got = ops.fused_outer_product_mean(*args)
+    if op == "triangle":
+        want = ref.triangle_mult_ref(*args)
+    else:
+        want = ref.outer_product_mean_ref(*args)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=ATOL[jnp.float32], rtol=1e-2)
 
 
 def test_triangle_oracle_forced_env(monkeypatch):
