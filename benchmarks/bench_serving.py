@@ -9,9 +9,8 @@ the event stream with ``repro.obs.report``. The checked-in
 ``BENCH_serving.json`` rows are keyed by the row's full serialized
 ExecutionPlan (``plan.to_dict()`` — never the process-salted hash) and
 carry the measured p50/p95/p99 queued->done latency, tokens/sec, mean slot
-occupancy, jit-entry census, and the roofline-referenced hardware
-efficiency per phase. ``python -m repro.obs report --bench`` (CI leg 8)
-schema-validates both the JSONL stream and this payload.
+occupancy and jit-entry census. ``python -m repro.obs report --bench`` (CI
+leg 8) schema-validates both the JSONL stream and this payload.
 
 Smoke mode (``--smoke``) shrinks the trace for the CI gate; the artifact
 records which mode produced it so trend tooling never compares smoke
@@ -38,7 +37,7 @@ def make_trace(n_requests: int, max_seq: int, seed: int) -> list:
 
 def bench_preset(name, plan, params, cfg, prompts, *, n_slots, max_seq,
                  max_new):
-    from repro.obs import aggregate, hardware_efficiency, use_tracer
+    from repro.obs import aggregate, use_tracer
     from repro.serving.engine import ServingEngine
 
     with use_tracer() as tr:
@@ -68,10 +67,6 @@ def bench_preset(name, plan, params, cfg, prompts, *, n_slots, max_seq,
         "occupancy_mean": round(occ.get("mean", 0.0), 3),
         "occupancy_hist": occ.get("hist", {}),
         "jit_entries": agg["jit"],
-        "efficiency": {
-            phase: {k: (round(v, 6) if isinstance(v, float) else v)
-                    for k, v in e.items()}
-            for phase, e in hardware_efficiency(agg).items()},
     }
     return row, tr
 

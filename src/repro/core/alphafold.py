@@ -132,10 +132,12 @@ def alphafold_iteration(params, batch, prev, cfg: AlphaFoldConfig, *,
     if dist is None:
         dist = current_plan().parallel.make_dist()
     dt = cfg.compute_dtype
-    msa, pair = embed_inputs(params, batch, cfg)
-    msa, pair = embed_recycle(params, msa, pair, prev, cfg)
-    msa = msa.astype(dt)
-    pair = pair.astype(dt)
+    with jax.named_scope("alphafold.embed"):
+        msa, pair = embed_inputs(params, batch, cfg)
+    with jax.named_scope("alphafold.recycle"):
+        msa, pair = embed_recycle(params, msa, pair, prev, cfg)
+        msa = msa.astype(dt)
+        pair = pair.astype(dt)
 
     seq_mask = batch["seq_mask"]
     pair_mask = seq_mask[:, :, None] * seq_mask[:, None, :]
@@ -144,7 +146,11 @@ def alphafold_iteration(params, batch, prev, cfg: AlphaFoldConfig, *,
         dist=dist, cfg=cfg.evoformer, rng=rng, train=train,
     )
 
-    single = dense(params["single_proj"], msa[:, 0].astype(jnp.float32))
+    with jax.named_scope("alphafold.heads"):
+        single = dense(params["single_proj"], msa[:, 0].astype(jnp.float32))
+        msa_logits = dense(params["msa_head"], msa.astype(jnp.float32))
+        distogram_logits = dense(params["dist_head"],
+                                 pair.astype(jnp.float32))
     coords, frames, traj = structure_module(
         params["structure"], single, pair.astype(jnp.float32), seq_mask,
         cfg.structure,
@@ -155,8 +161,8 @@ def alphafold_iteration(params, batch, prev, cfg: AlphaFoldConfig, *,
         "coords": coords,
         "frames": frames,
         "traj": traj,
-        "msa_logits": dense(params["msa_head"], msa.astype(jnp.float32)),
-        "distogram_logits": dense(params["dist_head"], pair.astype(jnp.float32)),
+        "msa_logits": msa_logits,
+        "distogram_logits": distogram_logits,
     }
 
 

@@ -504,56 +504,74 @@ def evoformer_block(
     rngs = list(jax.random.split(rng, 8)) if rng is not None else [None] * 8
 
     # ----- MSA stack (s-shard phase) -----
+    # Each sub-module runs under jax.named_scope("evoformer.<name>"), its
+    # residual add and layout swaps included, so the device trace attributes
+    # the block's time (and, under DAP, its collectives) by sub-module.
     msa = dist.constrain(msa, ("b", "m", None, None))
     pair = dist.constrain(pair, ("b", "m", None, None))
-    upd = msa_row_attention(params["msa_row"], msa, pair, seq_mask, dist, cfg)
-    msa = _residual_add(upd, msa, cfg.dropout_msa, rngs[0], 2, train)
+    with jax.named_scope("evoformer.msa_row_attention"):
+        upd = msa_row_attention(params["msa_row"], msa, pair, seq_mask, dist,
+                                cfg)
+        msa = _residual_add(upd, msa, cfg.dropout_msa, rngs[0], 2, train)
 
-    # all_to_all #1: s-shard -> r-shard.
-    msa = dist.all_to_all(msa, split_axis=2, concat_axis=1)
-    msa = dist.constrain(msa, ("b", None, "m", None))
-    msa_mask_r = dist.all_to_all(msa_mask, split_axis=2, concat_axis=1)
+    with jax.named_scope("evoformer.msa_col_attention"):
+        # all_to_all #1: s-shard -> r-shard.
+        msa = dist.all_to_all(msa, split_axis=2, concat_axis=1)
+        msa = dist.constrain(msa, ("b", None, "m", None))
+        msa_mask_r = dist.all_to_all(msa_mask, split_axis=2, concat_axis=1)
 
-    upd = msa_col_attention(params["msa_col"], msa, msa_mask_r, dist, cfg)
-    msa = _residual_add(upd, msa, 0.0, None, 0, train)
-    msa = _residual_add(msa_transition(params["msa_trans"], msa), msa,
-                        0.0, None, 0, train)
+        upd = msa_col_attention(params["msa_col"], msa, msa_mask_r, dist, cfg)
+        msa = _residual_add(upd, msa, 0.0, None, 0, train)
+    with jax.named_scope("evoformer.msa_transition"):
+        msa = _residual_add(msa_transition(params["msa_trans"], msa), msa,
+                            0.0, None, 0, train)
 
     # ----- Communication: OPM consumes the r-shard MSA -----
-    pair_upd = outer_product_mean(params["opm"], msa, msa_mask_r, dist, cfg)
+    with jax.named_scope("evoformer.outer_product_mean"):
+        pair_upd = outer_product_mean(params["opm"], msa, msa_mask_r, dist,
+                                      cfg)
 
-    # all_to_all #2 (the Duality-Async window): swap MSA back to s-shard now;
-    # its result is consumed only at the *next block's* row attention, so the
-    # entire pair stack below is overlap-eligible compute.
-    msa = dist.all_to_all(msa, split_axis=1, concat_axis=2)
-    msa = dist.constrain(msa, ("b", "m", None, None))
+        # all_to_all #2 (the Duality-Async window): swap MSA back to s-shard
+        # now; its result is consumed only at the *next block's* row
+        # attention, so the entire pair stack below is overlap-eligible
+        # compute.
+        msa = dist.all_to_all(msa, split_axis=1, concat_axis=2)
+        msa = dist.constrain(msa, ("b", "m", None, None))
 
-    pair = _residual_add(pair_upd, pair, cfg.dropout_pair, rngs[1], 1, train)
+        pair = _residual_add(pair_upd, pair, cfg.dropout_pair, rngs[1], 1,
+                             train)
 
     # ----- Pair stack (i-shard phase) -----
-    upd = triangle_mult_outgoing(params["tri_mult_out"], pair, pair_mask_loc,
+    with jax.named_scope("evoformer.triangle_mult_outgoing"):
+        upd = triangle_mult_outgoing(params["tri_mult_out"], pair,
+                                     pair_mask_loc, dist, cfg)
+        pair = _residual_add(upd, pair, cfg.dropout_pair, rngs[2], 1, train)
+
+    with jax.named_scope("evoformer.triangle_mult_incoming"):
+        pair_t = transpose_pair(pair, dist)
+        pair_mask_t = transpose_pair(pair_mask_loc[..., None], dist)[..., 0]
+        upd = triangle_mult_incoming(params["tri_mult_in"], pair, pair_t,
+                                     pair_mask_t, dist, cfg)
+        pair = _residual_add(upd, pair, cfg.dropout_pair, rngs[3], 1, train)
+
+    with jax.named_scope("evoformer.triangle_attention_starting"):
+        upd = triangle_attention(params["tri_attn_start"], pair, seq_mask,
                                  dist, cfg)
-    pair = _residual_add(upd, pair, cfg.dropout_pair, rngs[2], 1, train)
-
-    pair_t = transpose_pair(pair, dist)
-    pair_mask_t = transpose_pair(pair_mask_loc[..., None], dist)[..., 0]
-    upd = triangle_mult_incoming(params["tri_mult_in"], pair, pair_t,
-                                 pair_mask_t, dist, cfg)
-    pair = _residual_add(upd, pair, cfg.dropout_pair, rngs[3], 1, train)
-
-    upd = triangle_attention(params["tri_attn_start"], pair, seq_mask, dist, cfg)
-    pair = _residual_add(upd, pair, cfg.dropout_pair, rngs[4], 1, train)
+        pair = _residual_add(upd, pair, cfg.dropout_pair, rngs[4], 1, train)
 
     # Ending-node attention == starting-node attention on the transpose.
-    pair_t = transpose_pair(pair, dist)
-    upd_t = triangle_attention(params["tri_attn_end"], pair_t, seq_mask, dist, cfg)
-    upd = transpose_pair(upd_t, dist)
-    pair = _residual_add(upd, pair, cfg.dropout_pair, rngs[5], 2, train)
+    with jax.named_scope("evoformer.triangle_attention_ending"):
+        pair_t = transpose_pair(pair, dist)
+        upd_t = triangle_attention(params["tri_attn_end"], pair_t, seq_mask,
+                                   dist, cfg)
+        upd = transpose_pair(upd_t, dist)
+        pair = _residual_add(upd, pair, cfg.dropout_pair, rngs[5], 2, train)
 
-    pair = _residual_add(
-        transition(params["pair_trans"]["mlp"],
-                   layer_norm(params["pair_trans"]["ln"], pair)),
-        pair, 0.0, None, 0, train)
+    with jax.named_scope("evoformer.pair_transition"):
+        pair = _residual_add(
+            transition(params["pair_trans"]["mlp"],
+                       layer_norm(params["pair_trans"]["ln"], pair)),
+            pair, 0.0, None, 0, train)
     # Duality-Async window (paper §IV.C): the swap-back all_to_all above is
     # consumed only at the *next* block's row attention. Fencing its result
     # with the finished pair stack pins the collective inside this block —

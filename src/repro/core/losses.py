@@ -62,24 +62,27 @@ def _pairwise_local(rot, trans, pos):
 def alphafold_loss(outputs, batch, *, w_fape=0.5, w_msa=2.0, w_dist=0.3,
                    w_aux=0.5):
     """outputs: dict from the model; batch: ProteinBatch-style dict."""
-    seq_mask = batch["seq_mask"]
-    true_rot, true_trans = true_frames_from_ca(batch["pseudo_beta"])
-    rot, trans = outputs["frames"]
-    l_fape = fape(rot, trans, true_rot, true_trans, trans, batch["pseudo_beta"],
-                  seq_mask)
-    # Aux: mean FAPE over the structure-module trajectory.
-    traj_rot, traj_trans = outputs["traj"]
+    with jax.named_scope("alphafold.loss"):
+        seq_mask = batch["seq_mask"]
+        true_rot, true_trans = true_frames_from_ca(batch["pseudo_beta"])
+        rot, trans = outputs["frames"]
+        l_fape = fape(rot, trans, true_rot, true_trans, trans,
+                      batch["pseudo_beta"], seq_mask)
+        # Aux: mean FAPE over the structure-module trajectory.
+        traj_rot, traj_trans = outputs["traj"]
 
-    def traj_fape(rt):
-        r, t = rt
-        return fape(r, t, true_rot, true_trans, t, batch["pseudo_beta"], seq_mask)
+        def traj_fape(rt):
+            r, t = rt
+            return fape(r, t, true_rot, true_trans, t, batch["pseudo_beta"],
+                        seq_mask)
 
-    l_aux = jnp.mean(jax.vmap(traj_fape)((traj_rot, traj_trans)))
-    l_msa = masked_msa_loss(outputs["msa_logits"], batch["true_msa"],
-                            batch["bert_mask"])
-    l_dist = distogram_loss(outputs["distogram_logits"], batch["pseudo_beta"],
-                            seq_mask)
-    total = w_fape * l_fape + w_aux * l_aux + w_msa * l_msa + w_dist * l_dist
+        l_aux = jnp.mean(jax.vmap(traj_fape)((traj_rot, traj_trans)))
+        l_msa = masked_msa_loss(outputs["msa_logits"], batch["true_msa"],
+                                batch["bert_mask"])
+        l_dist = distogram_loss(outputs["distogram_logits"],
+                                batch["pseudo_beta"], seq_mask)
+        total = (w_fape * l_fape + w_aux * l_aux + w_msa * l_msa
+                 + w_dist * l_dist)
     return total, {
         "loss": total, "fape": l_fape, "aux_fape": l_aux,
         "masked_msa": l_msa, "distogram": l_dist,

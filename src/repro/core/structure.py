@@ -163,30 +163,32 @@ def init_structure_module(key, cfg: StructureConfig) -> Params:
 def structure_module(p: Params, s_init: jax.Array, z: jax.Array,
                      seq_mask: jax.Array, cfg: StructureConfig):
     """Returns (final_coords (B, r, 3), traj rot/trans per iteration)."""
-    b, r, _ = s_init.shape
-    s = dense(p["proj_s"], layer_norm(p["ln_s"], s_init))
-    z_n = layer_norm(p["ln_z"], z)
-    rot, trans = identity_frames((b, r))
+    with jax.named_scope("structure.module"):
+        b, r, _ = s_init.shape
+        s = dense(p["proj_s"], layer_norm(p["ln_s"], s_init))
+        z_n = layer_norm(p["ln_z"], z)
+        rot, trans = identity_frames((b, r))
 
-    def body(carry, _):
-        s, rot, trans = carry
-        s = s + ipa(p["ipa"], s, z_n, rot, trans, seq_mask, cfg)
-        s = layer_norm(p["ln_ipa"], s)
-        h = jax.nn.relu(dense(p["trans1"], s))
-        h = jax.nn.relu(dense(p["trans2"], h))
-        s = layer_norm(p["ln_trans"], s + dense(p["trans3"], h))
-        upd = dense(p["bb_update"], s)  # (b, r, 6)
-        quat = jnp.concatenate(
-            [jnp.ones((b, r, 1), upd.dtype), upd[..., :3]], axis=-1
+        def body(carry, _):
+            s, rot, trans = carry
+            s = s + ipa(p["ipa"], s, z_n, rot, trans, seq_mask, cfg)
+            s = layer_norm(p["ln_ipa"], s)
+            h = jax.nn.relu(dense(p["trans1"], s))
+            h = jax.nn.relu(dense(p["trans2"], h))
+            s = layer_norm(p["ln_trans"], s + dense(p["trans3"], h))
+            upd = dense(p["bb_update"], s)  # (b, r, 6)
+            quat = jnp.concatenate(
+                [jnp.ones((b, r, 1), upd.dtype), upd[..., :3]], axis=-1
+            )
+            rot_u = quat_to_rot(quat)
+            trans_u = upd[..., 3:] * cfg.trans_scale
+            # Frames updated by right-composition with the local update;
+            # gradients flow through rotations (no stop-grad: reduced variant
+            # trains fine).
+            rot, trans = compose_frames(rot, trans, rot_u, trans_u)
+            return (s, rot, trans), (rot, trans)
+
+        (s, rot, trans), traj = jax.lax.scan(
+            body, (s, rot, trans), None, length=cfg.n_iterations
         )
-        rot_u = quat_to_rot(quat)
-        trans_u = upd[..., 3:] * cfg.trans_scale
-        # Frames updated by right-composition with the local update; gradients
-        # flow through rotations (no stop-grad: reduced variant trains fine).
-        rot, trans = compose_frames(rot, trans, rot_u, trans_u)
-        return (s, rot, trans), (rot, trans)
-
-    (s, rot, trans), traj = jax.lax.scan(
-        body, (s, rot, trans), None, length=cfg.n_iterations
-    )
     return trans, (rot, trans), traj  # CA coords = frame origins

@@ -12,7 +12,12 @@ Each op:
     and with the jnp KV-scan recompute backward elsewhere; the remaining ops
     use analytic jnp backwards that XLA fuses,
   * falls back to the pure-jnp oracle (ref.py) when the shape is outside the
-    kernel envelope or kernels are globally disabled.
+    kernel envelope or kernels are globally disabled,
+  * runs under ``jax.named_scope("ops.<family>")`` (``_scoped``): the public
+    wrapper and the custom_vjp rules, so every HLO op of the family, on
+    whichever leg, forward, backward and remat recompute alike, carries the
+    family in its ``op_name`` metadata. The device trace attributes time by
+    those names; the scope strings are that interface.
 
 Toggle: every leg choice is read from the context-local ExecutionPlan
 (``repro.exec.plan.current_plan()`` / ``with use_plan(plan):``) at *trace*
@@ -44,6 +49,23 @@ from repro.kernels.layer_norm import layer_norm_pallas
 # on the v5e target (ROW_TILE rows * C * 4 B fp32 + headroom in ~16 MB VMEM).
 _MAX_SOFTMAX_C = 16384
 _MAX_NORM_C = 32768
+
+
+def _scoped(family: str):
+    """Decorator: run the function under ``jax.named_scope("ops." +
+    family)``. Scopes are trace-time metadata: no op, no numerics change.
+    A fresh scope per call: the object ``jax.named_scope`` returns keeps
+    the outer name stack on itself, so one shared object is not
+    re-entrant."""
+    name = "ops." + family
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapped(*args, **kw):
+            with jax.named_scope(name):
+                return fn(*args, **kw)
+        return wrapped
+    return deco
 
 
 def _interpret() -> bool:
@@ -120,12 +142,14 @@ def _softmax_op(scale, has_bias, has_mask, x, bias, mask):
     return _softmax_impl(scale, has_bias, has_mask, x, bias, mask)
 
 
+@_scoped("softmax")
 def _softmax_fwd(scale, has_bias, has_mask, x, bias, mask):
     y = _softmax_impl(scale, has_bias, has_mask, x, bias, mask)
     return y, (y, None if bias is None else bias.shape,
                None if mask is None else mask.shape)
 
 
+@_scoped("softmax")
 def _softmax_bwd(scale, has_bias, has_mask, res, g):
     y, bias_shape, mask_shape = res
     yf = y.astype(jnp.float32)
@@ -147,6 +171,7 @@ def _softmax_bwd(scale, has_bias, has_mask, res, g):
 _softmax_op.defvjp(_softmax_fwd, _softmax_bwd)
 
 
+@_scoped("softmax")
 def fused_softmax(
     x: jax.Array,
     bias: jax.Array | None = None,
@@ -318,6 +343,7 @@ def _attn_op(scale, has_bias, has_mask, kv_tile, leg, bwd, q, k, v, bias,
     return out
 
 
+@_scoped("attention")
 def _attn_fwd(scale, has_bias, has_mask, kv_tile, leg, bwd, q, k, v, bias,
               mask):
     out, lse = _attn_fwd_impl(scale, has_bias, has_mask, kv_tile, leg, q, k,
@@ -363,6 +389,7 @@ def _attn_bwd_pallas(scale, has_bias, has_mask, kv_tile, leg, res, g):
     return dq, dk, dv, db, dm
 
 
+@_scoped("attention")
 def _attn_bwd(scale, has_bias, has_mask, kv_tile, leg, bwd, res, g):
     """Recompute backward. On the Pallas leg (TPU, or forced interpret) and
     in-envelope shapes: the fused flash_attention_bwd_pallas kernel. Oracle
@@ -433,6 +460,7 @@ def _attn_bwd(scale, has_bias, has_mask, kv_tile, leg, bwd, res, g):
 _attn_op.defvjp(_attn_fwd, _attn_bwd)
 
 
+@_scoped("attention")
 def fused_attention(
     q: jax.Array,
     k: jax.Array,
@@ -565,6 +593,7 @@ def _tri_op(eps, tile, leg, a_lin, ga, mask, b_full, gamma, beta, w_out,
     return out
 
 
+@_scoped("triangle")
 def _tri_fwd(eps, tile, leg, a_lin, ga, mask, b_full, gamma, beta, w_out,
              b_out, g_lin, g_bias):
     out, mean, inv = _tri_fwd_impl(eps, tile, leg, a_lin, ga, mask, b_full,
@@ -576,6 +605,7 @@ def _tri_fwd(eps, tile, leg, a_lin, ga, mask, b_full, gamma, beta, w_out,
                  g_bias, mean, inv, out)
 
 
+@_scoped("triangle")
 def _tri_bwd(eps, tile, leg, res, g):
     from repro.kernels.triangle import triangle_mult_bwd
 
@@ -585,6 +615,7 @@ def _tri_bwd(eps, tile, leg, res, g):
 _tri_op.defvjp(_tri_fwd, _tri_bwd)
 
 
+@_scoped("triangle")
 def fused_triangle_mult(
     a_lin: jax.Array,
     ga: jax.Array,
@@ -643,6 +674,7 @@ def _opm_op(tile, leg, a, b_full, mask_a, mask_b, w, bias):
     return _opm_fwd_impl(tile, leg, a, b_full, mask_a, mask_b, w, bias)
 
 
+@_scoped("opm")
 def _opm_fwd(tile, leg, a, b_full, mask_a, mask_b, w, bias):
     out = _opm_fwd_impl(tile, leg, a, b_full, mask_a, mask_b, w, bias)
     # Residuals: inputs + the (already HBM-resident) output — `out` turns
@@ -651,6 +683,7 @@ def _opm_fwd(tile, leg, a, b_full, mask_a, mask_b, w, bias):
     return out, (a, b_full, mask_a, mask_b, w, bias, out)
 
 
+@_scoped("opm")
 def _opm_bwd(tile, leg, res, g):
     from repro.kernels.triangle import opm_bwd
 
@@ -660,6 +693,7 @@ def _opm_bwd(tile, leg, res, g):
 _opm_op.defvjp(_opm_fwd, _opm_bwd)
 
 
+@_scoped("opm")
 def fused_outer_product_mean(
     a: jax.Array,
     b_full: jax.Array,
@@ -709,10 +743,12 @@ def _ln_op(eps, x, gamma, beta):
     return _ln_impl(eps, x, gamma, beta)
 
 
+@_scoped("layer_norm")
 def _ln_fwd(eps, x, gamma, beta):
     return _ln_impl(eps, x, gamma, beta), (x, gamma)
 
 
+@_scoped("layer_norm")
 def _ln_bwd(eps, res, g):
     x, gamma = res
     xf = x.astype(jnp.float32)
@@ -736,6 +772,7 @@ def _ln_bwd(eps, res, g):
 _ln_op.defvjp(_ln_fwd, _ln_bwd)
 
 
+@_scoped("layer_norm")
 def layer_norm(x: jax.Array, gamma: jax.Array, beta: jax.Array,
                eps: float = 1e-5) -> jax.Array:
     """LayerNorm over the last axis; any leading shape.
@@ -772,10 +809,12 @@ def _bsm_op(g, bg, v):
     return _bsm_impl(g, bg, v)
 
 
+@_scoped("bias_sigmoid_mul")
 def _bsm_fwd(g, bg, v):
     return _bsm_impl(g, bg, v), (g, bg, v)
 
 
+@_scoped("bias_sigmoid_mul")
 def _bsm_bwd(res, grad):
     g, bg, v = res
     gradf = grad.astype(jnp.float32)
@@ -790,6 +829,7 @@ def _bsm_bwd(res, grad):
 _bsm_op.defvjp(_bsm_fwd, _bsm_bwd)
 
 
+@_scoped("bias_sigmoid_mul")
 def bias_sigmoid_mul(g: jax.Array, bg: jax.Array, v: jax.Array) -> jax.Array:
     """sigmoid(g + bg) * v; g and v share shape (..., C), bg is (C,).
 
@@ -828,10 +868,12 @@ def _bda_op(rate, x, b, residual, keep):
     return _bda_impl(rate, x, b, residual, keep)
 
 
+@_scoped("bias_dropout_add")
 def _bda_fwd(rate, x, b, residual, keep):
     return _bda_impl(rate, x, b, residual, keep), (keep,)
 
 
+@_scoped("bias_dropout_add")
 def _bda_bwd(rate, res, g):
     (keep,) = res
     gf = g.astype(jnp.float32)
@@ -846,6 +888,7 @@ def _bda_bwd(rate, res, g):
 _bda_op.defvjp(_bda_fwd, _bda_bwd)
 
 
+@_scoped("bias_dropout_add")
 def bias_dropout_add(
     x: jax.Array,
     b: jax.Array | None,

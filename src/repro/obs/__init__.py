@@ -89,8 +89,8 @@ stream and BENCH_serving.json).
 from repro.obs.events import (KINDS, REQUEST_PHASES, SCHEMA_VERSION,
                               TERMINAL_PHASES, read_jsonl, validate_event,
                               validate_events)
-from repro.obs.report import (aggregate, hardware_efficiency, quantiles,
-                              reconcile, render_report, validate_bench)
+from repro.obs.report import (aggregate, quantiles, reconcile, render_report,
+                              validate_bench)
 from repro.obs.trace import (Tracer, count, current_tracer, emit, gauge,
                              json_safe, monotonic_ns, span, timed_call,
                              use_tracer)
@@ -98,7 +98,7 @@ from repro.obs.trace import (Tracer, count, current_tracer, emit, gauge,
 __all__ = [
     "KINDS", "REQUEST_PHASES", "SCHEMA_VERSION", "TERMINAL_PHASES",
     "Tracer", "aggregate", "count", "current_tracer", "emit", "gauge",
-    "hardware_efficiency", "json_safe", "monotonic_ns", "quantiles",
+    "json_safe", "monotonic_ns", "quantiles",
     "read_jsonl", "reconcile", "render_report", "span", "timed_call",
     "use_tracer", "validate_bench", "validate_event", "validate_events",
 ]

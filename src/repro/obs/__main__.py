@@ -2,8 +2,8 @@
 
 Validates an event stream against the stable schema, renders the
 aggregated report (span percentiles + self-time, request lifecycle
-tallies, occupancy histograms, jit-entry churn, roofline-referenced
-hardware-efficiency fractions), and checks the request-lifecycle
+tallies, occupancy histograms, jit-entry churn), and checks the
+request-lifecycle
 reconciliation invariant. With ``--bench`` it additionally schema-checks
 a BENCH_serving.json payload. ``--strict`` turns any schema or
 reconciliation problem into a nonzero exit (the CI leg-8 mode).

@@ -10,19 +10,8 @@ Pure-python passes over the stable event schema (``repro/obs/events.py``):
   reconcile(events)   lifecycle invariant check: every queued request ends
                       in exactly one terminal phase (done|failed), no
                       terminal without a queued, no post-terminal events.
-  hardware_efficiency(agg)
-                      cross-references measured per-token prefill/decode
-                      time against the roofline model's hardware constants
-                      (launch/mesh.py: peak FLOP/s + HBM bandwidth) using
-                      the model facts the engine put in its ``meta`` event
-                      — prints the fraction of roofline each phase
-                      achieves. The modeled floor is per *chip* (TPU v5e);
-                      on a CPU dev box the fraction is honest and tiny.
   render_report(events)
                       the ``python -m repro.obs report`` body.
-
-Only ``hardware_efficiency`` touches jax-adjacent code (a lazy import of
-the mesh constants); everything else runs anywhere.
 """
 from __future__ import annotations
 
@@ -205,57 +194,6 @@ def reconcile(events: list[dict]) -> list[str]:
     return problems
 
 
-def hardware_efficiency(agg: dict) -> dict:
-    """Measured-vs-roofline per phase. Needs the engine ``meta`` facts
-    (param_count/param_bytes/cache_row_bytes); returns {} without them."""
-    meta = agg["meta"]
-    needed = ("param_count", "param_bytes", "cache_row_bytes")
-    if not all(k in meta for k in needed):
-        return {}
-    from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16  # lazy: jax import
-
-    param_count = float(meta["param_count"])
-    param_bytes = float(meta["param_bytes"])
-    row_bytes = float(meta["cache_row_bytes"])
-    out: dict[str, dict] = {}
-
-    # Decode: each emitted token costs ~2*params FLOPs and must stream the
-    # weights + its KV row from HBM (batching amortizes the weight stream
-    # across the group — this floor assumes perfect amortization at the
-    # mean measured batch, so the fraction is an upper bound on headroom).
-    tokens = agg["counters"].get("tokens_decoded", 0.0)
-    dec = agg["spans"].get("decode")
-    if dec and tokens:
-        batch = max(1.0, tokens / max(1, dec["count"]))
-        # Execute-side time (block_ns) when the spans carry the jax-timed
-        # split — compile cost lives in dispatch_ns and must not be billed
-        # against the hardware; fall back to wall time otherwise.
-        measured_s = (dec["exec_ns"] or dec["total_ns"]) / 1e9 / tokens
-        roofline_s = max(2.0 * param_count / PEAK_FLOPS_BF16,
-                         (param_bytes / batch + row_bytes) / HBM_BW)
-        out["decode"] = _phase(measured_s, roofline_s, tokens)
-
-    # Prefill: 2*params FLOPs per prompt token; one weight stream per call.
-    pre = agg["spans"].get("prefill")
-    p_tokens = agg["requests"]["prompt_tokens"]
-    if pre and p_tokens:
-        measured_s = (pre["exec_ns"] or pre["total_ns"]) / 1e9 / p_tokens
-        roofline_s = max(2.0 * param_count / PEAK_FLOPS_BF16,
-                         param_bytes / max(1, p_tokens / pre["count"])
-                         / HBM_BW)
-        out["prefill"] = _phase(measured_s, roofline_s, p_tokens)
-    return out
-
-
-def _phase(measured_s: float, roofline_s: float, tokens: float) -> dict:
-    return {
-        "tokens": tokens,
-        "measured_us_per_token": measured_s * 1e6,
-        "roofline_us_per_token": roofline_s * 1e6,
-        "efficiency": roofline_s / measured_s if measured_s > 0 else 0.0,
-    }
-
-
 def render_report(events: list[dict]) -> str:
     agg = aggregate(events)
     lines = [f"obs report: {len(events)} events"]
@@ -306,12 +244,6 @@ def render_report(events: list[dict]) -> str:
         lines.append(f"  jit {site}: {j['calls']} calls, "
                      f"{j['distinct_keys']} distinct plan key(s), "
                      f"{j['misses']} trace miss(es){churn}")
-    eff = hardware_efficiency(agg)
-    for phase, e in sorted(eff.items()):
-        lines.append(
-            f"  roofline {phase}: measured {e['measured_us_per_token']:.1f}"
-            f" us/token vs modeled floor {e['roofline_us_per_token']:.3f} "
-            f"us/token -> {e['efficiency']:.2%} of hardware")
     problems = reconcile(events)
     if problems:
         lines.append(f"  RECONCILE: {len(problems)} problem(s)")

@@ -18,6 +18,11 @@ the deterministic ordering key: two runs of the same deterministic workload
 produce the same event sequence (kinds/names/attrs), differing only in the
 ``*_ns`` fields.
 
+Each span (``span`` and ``timed_call``) of a scoped tracer also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so in any profile an
+operator takes (``jax.profiler.trace``) the span lies on the profiler's own
+clock, on the ``/host:CPU`` plane beside the device ops it dispatched.
+
 The jax-aware timer (``timed_call``) separates host dispatch from device
 execution via ``block_until_ready``: ``dispatch_ns`` is the host time for
 the call to return (on a cold jit cache this is dominated by trace+compile
@@ -38,9 +43,10 @@ records them *without* forcing a host sync); they are resolved to floats
 only when the tracer serializes (``events_resolved``/``dump_jsonl``) — off
 the hot path by construction.
 
-This module imports no jax (the ``timed_call`` import is local) and is
-single-thread-per-tracer by design: the two instrumented loops (the serving
-engine and the train step loop) are host-side sequential loops.
+This module imports no jax (the imports in ``timed_call`` and
+``_annotation`` are local) and is single-thread-per-tracer by design: the
+two instrumented loops (the serving engine and the train step loop) are
+host-side sequential loops.
 """
 from __future__ import annotations
 
@@ -57,6 +63,14 @@ def monotonic_ns() -> int:
     entry point instead of reading ``time.*`` in traced modules (lint R003).
     """
     return time.perf_counter_ns()
+
+
+def _annotation(name: str):
+    """The span on the profiler's clock: a ``TraceAnnotation``, which
+    records an event only while a profile is being taken."""
+    from jax.profiler import TraceAnnotation  # local: no jax at import
+
+    return TraceAnnotation(name)
 
 
 def json_safe(v: Any) -> Any:
@@ -118,7 +132,8 @@ class Tracer:
         t0 = self._now()
         status = "ok"
         try:
-            yield span_id
+            with _annotation(name):
+                yield span_id
         # status-only observer: re-raises unconditionally, so the typed
         # fault hierarchy passes through untouched
         # repro-lint: disable=R002
@@ -142,11 +157,12 @@ class Tracer:
         span_id = self._next_span
         self._next_span += 1
         parent = self._span_stack[-1] if self._span_stack else None
-        t0 = self._now()
-        out = fn(*args, **kw)
-        t1 = self._now()
-        jax.block_until_ready(out)
-        t2 = self._now()
+        with _annotation(name):
+            t0 = self._now()
+            out = fn(*args, **kw)
+            t1 = self._now()
+            jax.block_until_ready(out)
+            t2 = self._now()
         self.emit("span", name, span_id=span_id, parent_id=parent,
                   t_start_ns=t0, dur_ns=t2 - t0, status="ok",
                   attrs={**(attrs or {}),

@@ -92,35 +92,42 @@ def make_train_step(
             loss = loss_sum / accum_steps
             metrics = {"loss": loss}
 
-        grads, gnorm = clip_by_global_norm(grads, clip_norm)
-        metrics = dict(metrics)
-        # Contract: "loss" is always present, whether or not the loss_fn's
-        # aux dict reports one of its own (an aux "loss" wins — it may be
-        # the unscaled/per-token variant the caller prefers to log).
-        metrics.setdefault("loss", loss)
-        if guard_nonfinite:
-            # One non-finite leaf makes gnorm (the global L2) non-finite, so
-            # this single scalar guards the whole grad tree. Feed zeros to
-            # the optimizer so NaNs never propagate, then discard the
-            # update via where-select — when healthy, where(True, x, .) is
-            # x, bit for bit.
-            ok = jnp.isfinite(gnorm)
-            grads = jax.tree.map(
-                lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads)
-            metrics["nonfinite_skips"] = (~ok).astype(jnp.float32)
-        else:
-            # Guard off: the key is still reported (constant 0.0) so the
-            # metrics schema is never ragged across configurations.
-            metrics["nonfinite_skips"] = jnp.zeros((), jnp.float32)
-        lr = cosine_schedule(state.step, base_lr, warmup_steps, total_steps)
-        new_params, new_opt = opt_update(
-            state.params, grads, state.opt_state, lr,
-            weight_decay=weight_decay)
-        if guard_nonfinite:
-            new_params = jax.tree.map(
-                lambda n, o: jnp.where(ok, n, o), new_params, state.params)
-            new_opt = jax.tree.map(
-                lambda n, o: jnp.where(ok, n, o), new_opt, state.opt_state)
+        # Clip, schedule and update run under one scope, so the device trace
+        # attributes the optimizer's time.
+        with jax.named_scope("train.optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, clip_norm)
+            metrics = dict(metrics)
+            # Contract: "loss" is always present, whether or not the
+            # loss_fn's aux dict reports one of its own (an aux "loss" wins —
+            # it may be the unscaled/per-token variant the caller prefers to
+            # log).
+            metrics.setdefault("loss", loss)
+            if guard_nonfinite:
+                # One non-finite leaf makes gnorm (the global L2) non-finite,
+                # so this single scalar guards the whole grad tree. Feed
+                # zeros to the optimizer so NaNs never propagate, then
+                # discard the update via where-select — when healthy,
+                # where(True, x, .) is x, bit for bit.
+                ok = jnp.isfinite(gnorm)
+                grads = jax.tree.map(
+                    lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads)
+                metrics["nonfinite_skips"] = (~ok).astype(jnp.float32)
+            else:
+                # Guard off: the key is still reported (constant 0.0) so the
+                # metrics schema is never ragged across configurations.
+                metrics["nonfinite_skips"] = jnp.zeros((), jnp.float32)
+            lr = cosine_schedule(state.step, base_lr, warmup_steps,
+                                 total_steps)
+            new_params, new_opt = opt_update(
+                state.params, grads, state.opt_state, lr,
+                weight_decay=weight_decay)
+            if guard_nonfinite:
+                new_params = jax.tree.map(
+                    lambda n, o: jnp.where(ok, n, o), new_params,
+                    state.params)
+                new_opt = jax.tree.map(
+                    lambda n, o: jnp.where(ok, n, o), new_opt,
+                    state.opt_state)
         metrics.update({"grad_norm": gnorm, "lr": lr})
         return TrainState(state.step + 1, new_params, new_opt), metrics
 
