@@ -4,9 +4,11 @@
 
 Folds ``configs.alphafold.FULL`` (3 recycles) on ``chip_smoke.py``'s batch
 (n_res 256, n_seq 128, batch 1) under plans that move one kernel family at
-a time from its Pallas leg to its XLA leg, then under the oracle preset.
-The step between two rows is what that family's Pallas kernels cost or
-save in the fold. Prints one ``AB <plan>:`` line per plan: compile seconds,
+a time from its Pallas leg to its XLA leg, then under the oracle preset:
+every family on Pallas (the row-wise ones named explicitly), the default
+(row-wise families on XLA), triangle and OPM on XLA as well, attention as
+well. The step between two rows is what that family's Pallas kernels cost
+or save in the fold. Prints one ``AB <plan>:`` line per plan: compile seconds,
 three fold seconds (``block_until_ready``) and the program's
 ``tpu_custom_call`` count. Exits non-zero off a TPU.
 """
@@ -26,12 +28,12 @@ RUNS = 3
 def plans() -> dict:
     from repro.exec.plan import ExecutionPlan, KernelPolicy, preset
 
-    rows = dict(layer_norm="xla", elementwise="xla")
-    pair = dict(rows, triangle="xla", opm="xla")
+    rows = dict(softmax="pallas", layer_norm="pallas", elementwise="pallas")
+    pair = dict(triangle="xla", opm="xla")
     return {
+        "rows_pallas": ExecutionPlan(kernels=KernelPolicy(**rows)),
         "default": ExecutionPlan(),
-        "rows_xla": ExecutionPlan(kernels=KernelPolicy(**rows)),
-        "rows_tri_opm_xla": ExecutionPlan(kernels=KernelPolicy(**pair)),
+        "tri_opm_xla": ExecutionPlan(kernels=KernelPolicy(**pair)),
         "all_xla": ExecutionPlan(kernels=KernelPolicy(**pair,
                                                       attention="xla")),
         "oracle": preset("oracle"),

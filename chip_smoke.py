@@ -26,9 +26,10 @@ the default-vs-oracle comparison blind to the kernels, so the zero leaves
 are redrawn at a small scale.
 
 Exits non-zero, with no result line, when: no TPU is found; a kernel family
-on the path resolves to a leg other than ``pallas`` (an ``REPRO_PLAN``
-override, an envelope fallback; under GSPMD the row-wise families take
-``xla``, see ``kernels/ops.py``); a compiled program holds no
+on the path resolves to a leg other than its default on TPU (``pallas`` for
+attention, triangle and OPM, ``xla`` for the row-wise families, see
+``kernels/ops.py``): an ``REPRO_PLAN`` override or an envelope fallback; a
+compiled program holds no
 ``tpu_custom_call``; a loss is non-finite; a comparison misses its
 tolerance; or any phase raises. Otherwise the last stdout line is
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
@@ -80,18 +81,17 @@ GRAD_RTOL = 3e-2
 LOSS_RTOL = 1e-3
 # pallas_call wrappers (the ``jit(<name>)`` in each custom call's op_name)
 # that a compiled program of each kind must contain.
+# The row-wise families run on XLA (kernels/ops.py ``kernel_leg``), so no
+# program holds their kernels.
 FOLD_KERNELS = ("flash_attention_pallas", "fused_triangle_pallas",
-                "fused_opm_pallas", "layer_norm_pallas",
-                "bias_sigmoid_mul_pallas")
-TRAIN_KERNELS = FOLD_KERNELS + ("flash_attention_bwd_pallas",
-                                "bias_dropout_add_pallas")
-# Under GSPMD the row-wise ops see global arrays and take their XLA leg
-# (kernels/ops.py ``kernel_leg``); the pair-stack and attention kernels run
-# inside the dist backend's shard_map.
-DAP_KERNELS = FOLD_KERNELS[:3]
+                "fused_opm_pallas")
+TRAIN_KERNELS = FOLD_KERNELS + ("flash_attention_bwd_pallas",)
+# Under GSPMD the pair-stack and attention kernels run inside the dist
+# backend's shard_map, as on one device.
+DAP_KERNELS = FOLD_KERNELS
 DAP_DEVICES = 4
-OP_FAMILIES = ("attention", "triangle", "opm", "layer_norm", "elementwise")
-ROW_FAMILIES = ("layer_norm", "elementwise")
+ROW_FAMILIES = ("softmax", "layer_norm", "elementwise")
+OP_FAMILIES = ("attention", "triangle", "opm") + ROW_FAMILIES
 
 
 class SmokeFailure(RuntimeError):
@@ -134,9 +134,10 @@ def check_census(label: str, compiled, expected) -> None:
 
 
 def check_legs(plan, cfg) -> None:
-    """Every op family resolves to the compiled Pallas leg (the row-wise ones
-    to XLA under GSPMD), at this model's shapes (no envelope fallback), and
-    no env plan overrides the default."""
+    """Every op family resolves to its default leg on TPU (the compiled
+    Pallas kernel; XLA for the row-wise families, on every backend), at this
+    model's shapes (no envelope fallback), and no env plan overrides the
+    default."""
     from repro.exec.plan import ExecutionPlan, use_plan
     from repro.kernels import ops
 
@@ -145,8 +146,7 @@ def check_legs(plan, cfg) -> None:
           f"the environment selects a non-default plan: {env_plan.describe()}")
     evo = cfg.evoformer
     dt = cfg.compute_dtype
-    gspmd = plan.parallel.backend == "gspmd"
-    want = {op: "xla" if gspmd and op in ROW_FAMILIES else "pallas"
+    want = {op: "xla" if op in ROW_FAMILIES else "pallas"
             for op in OP_FAMILIES}
     with use_plan(plan):
         legs = {op: ops.kernel_leg(op) for op in OP_FAMILIES}

@@ -23,10 +23,10 @@ each op family in ``kernels/ops.py`` (``auto`` is the default everywhere):
                                  interpret mode)
     triangle    Pallas kernel   XLA j-block scan          pallas | interpret |
     opm         Pallas kernel   XLA reassociated GEMMs      xla | oracle
-    softmax     Pallas kernel   jnp oracle (its XLA leg)  pallas | interpret |
-    layer_norm  Pallas kernel   jnp oracle                  xla | oracle
-    elementwise Pallas kernel   jnp oracle                (xla == oracle for
-                                                           these op families)
+    softmax     jnp oracle      jnp oracle                pallas | interpret |
+    layer_norm  jnp oracle      jnp oracle                  xla | oracle
+    elementwise jnp oracle      jnp oracle                (xla == oracle for
+                (its XLA leg)   (its XLA leg)              these op families)
     attn_bwd    fused Pallas    jnp KV-scan recompute     auto | scan
                 backward
 
@@ -44,11 +44,16 @@ each op family in ``kernels/ops.py`` (``auto`` is the default everywhere):
     ``use_plan`` even though the backward is traced later.
   * Off-TPU, an explicit ``"pallas"`` runs the kernel in interpret mode
     (there is no compiled Pallas backend to target).
-  * Under ``ParallelPolicy(backend="gspmd")`` on TPU, ``"auto"`` resolves
-    softmax / layer_norm / elementwise to their XLA leg: those ops see
-    global, mesh-sharded arrays, and GSPMD cannot partition a Pallas call
-    (attention, triangle and OPM run their kernels inside the dist
-    backend's shard_map).
+  * On TPU ``"auto"`` resolves softmax / layer_norm / elementwise to their
+    XLA leg under every parallel backend (local, shard_map, gspmd). Their
+    Pallas kernels move one 8-row tile (2-4 KB of bf16) per grid step, so a
+    fixed cost per step, not HBM bandwidth, sets their time; XLA fuses the
+    same float32 row math into the neighbouring ops. On one v5e the
+    full-width train step ran 7.455 s with them on Pallas and 5.166 s on XLA
+    (PERF.md section 5). The XLA leg is also what GSPMD can partition on the
+    global arrays these ops see under the gspmd backend (attention, triangle
+    and OPM run their kernels inside the dist backend's shard_map). An
+    explicit ``"pallas"`` still runs the kernels, for A/B runs.
 
 ``ParallelPolicy`` subsumes the hand-threaded ``dist=`` kwarg (the backend is
 built once via ``make_dist()``), ``MemoryPolicy`` subsumes ``hbm_budget=``

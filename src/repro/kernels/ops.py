@@ -2,11 +2,14 @@
 
 Each op:
   * reshapes arbitrary leading dims down to the kernel's canonical layout,
-  * runs the Pallas kernel on TPU (the target); on other backends it runs an
-    XLA-native leg with identical semantics (the jnp oracle for the
-    element-wise/softmax/LN ops, the online-softmax lax.scan for fused
-    attention) — interpret-mode Pallas is a per-grid-cell loop that only runs
-    when the plan asks for interpret mode (the kernel-validation CI leg),
+  * runs the Pallas kernel on TPU (the target) for attention, triangle and
+    OPM; the element-wise/softmax/LN ops run their XLA leg there by default
+    (``kernel_leg``), their kernels only when a plan names them. On other
+    backends each op runs an XLA-native leg with identical semantics (the jnp
+    oracle for the element-wise/softmax/LN ops, the online-softmax lax.scan
+    for fused attention) — interpret-mode Pallas is a per-grid-cell loop that
+    only runs when the plan asks for interpret mode (the kernel-validation CI
+    leg),
   * carries a ``jax.custom_vjp``: fused attention pairs the forward with the
     fused Pallas backward (``flash_attention_bwd_pallas``) on the Pallas leg
     and with the jnp KV-scan recompute backward elsewhere; the remaining ops
@@ -72,34 +75,35 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-# Row-wise op families called on whole (possibly mesh-sharded) arrays. The
-# attention/triangle/OPM kernels reach GSPMD only inside the dist backend's
-# shard_map; these do not, and GSPMD cannot partition a Mosaic call.
+# Row-wise op families: ``auto`` runs them on their XLA leg on TPU too.
 _ROW_OPS = ("softmax", "layer_norm", "elementwise")
 
 
 def kernel_leg(op: str) -> str:
     """Resolved execution leg for an op family under the current plan:
     'pallas' | 'interpret' | 'xla' | 'oracle'. An explicit per-op leg on
-    KernelPolicy wins; 'auto' resolves to the Pallas kernel on TPU (the
-    target) and to the op's XLA-native leg elsewhere — interpret-mode Pallas
-    (a per-grid-cell loop) only under ``KernelPolicy.interpret`` (the
+    KernelPolicy wins. 'auto' resolves on TPU (the target) to the Pallas
+    kernel for attention, triangle and OPM, and to the XLA leg for the
+    row-wise families (``_ROW_OPS``) under every parallel backend: their
+    Pallas kernels move one 8-row tile (2-4 KB of bf16) per grid step, so a
+    fixed cost per step, not HBM bandwidth, sets their time, while XLA fuses
+    the same float32 math into the neighbouring ops. On one v5e the
+    full-width train step ran 7.455 s with them on Pallas and 5.166 s on XLA
+    (PERF.md section 5); under the 'gspmd' backend the XLA leg is also what
+    GSPMD partitions on the global arrays these ops see. Off TPU 'auto'
+    resolves to the op's XLA-native leg — interpret-mode Pallas (a
+    per-grid-cell loop) only under ``KernelPolicy.interpret`` (the
     kernel-validation CI leg), which is both faster on CPU and safe to lower
     inside large SPMD dry-runs. ``enabled=False`` sends every 'auto' op to
-    its jnp oracle. Under the 'gspmd' parallel backend the row-wise families
-    (``_ROW_OPS``) resolve to their XLA leg on TPU too: their arrays are
-    global there, and XLA fuses and partitions the row math itself."""
-    plan = current_plan()
-    pol = plan.kernels
+    its jnp oracle."""
+    pol = current_plan().kernels
     leg = getattr(pol, op)
     if leg != "auto":
         return leg
     if not pol.enabled:
         return "oracle"
     if jax.default_backend() == "tpu":
-        if op in _ROW_OPS and plan.parallel.backend == "gspmd":
-            return "xla"
-        return "pallas"
+        return "xla" if op in _ROW_OPS else "pallas"
     return "interpret" if pol.interpret else "xla"
 
 
@@ -107,8 +111,10 @@ def _use_pallas(leg: str) -> bool:
     """Whether a resolved leg executes the Pallas kernel (off-TPU both
     'pallas' and 'interpret' run it in interpret mode — there is no compiled
     Pallas backend to target there). For the element-wise/softmax/LN ops the
-    'xla' leg IS the jnp oracle (XLA fuses it), so this is their whole
-    routing decision."""
+    'xla' leg IS the float32 jnp oracle (ref.py), which XLA fuses into its
+    neighbours and which ``auto`` selects on TPU (``kernel_leg``); their
+    Pallas kernels run only when a plan names 'pallas' or 'interpret'. So
+    this is their whole routing decision."""
     return leg in ("pallas", "interpret")
 
 

@@ -117,6 +117,50 @@ def test_kernel_policy_validates_legs():
 
 
 # ---------------------------------------------------------------------------
+# the leg rule on TPU
+# ---------------------------------------------------------------------------
+
+
+_ROW_FAMILIES = ("softmax", "layer_norm", "elementwise")
+_KERNEL_FAMILIES = ("attention", "triangle", "opm")
+
+
+def _tpu_rule_plans():
+    """name -> (plan, expected leg per op family) for the TPU leg rule."""
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
+                             ("data", "model"))
+    default = {**{op: "xla" for op in _ROW_FAMILIES},
+               **{op: "pallas" for op in _KERNEL_FAMILIES}}
+    explicit = KernelPolicy(softmax="pallas", layer_norm="pallas",
+                            elementwise="pallas")
+    return {
+        "default": (ExecutionPlan(), default),
+        "gspmd": (ExecutionPlan().with_parallel(backend="gspmd", mesh=mesh),
+                  default),
+        "shard_map": (ExecutionPlan().with_parallel(backend="shard_map"),
+                      default),
+        "explicit_pallas": (ExecutionPlan(kernels=explicit),
+                            dict.fromkeys(default, "pallas")),
+        "disabled": (ExecutionPlan(kernels=KernelPolicy(enabled=False)),
+                     dict.fromkeys(default, "oracle")),
+    }
+
+
+@pytest.mark.parametrize("op", _ROW_FAMILIES + _KERNEL_FAMILIES)
+@pytest.mark.parametrize("plan_name", ["default", "gspmd", "shard_map",
+                                       "explicit_pallas", "disabled"])
+def test_kernel_leg_rule_on_tpu(clean_env, plan_name, op):
+    """On TPU ``auto`` sends the row-wise families to XLA and attention,
+    triangle and OPM to their Pallas kernels, under every parallel backend;
+    an explicit 'pallas' leg still reaches the row-wise kernels and
+    ``enabled=False`` still gives the oracle."""
+    plan, want = _tpu_rule_plans()[plan_name]
+    clean_env.setattr(jax, "default_backend", lambda: "tpu")
+    with use_plan(plan):
+        assert ops.kernel_leg(op) == want[op]
+
+
+# ---------------------------------------------------------------------------
 # hashability / jit-cache contract + leg numerics
 # ---------------------------------------------------------------------------
 

@@ -1,4 +1,6 @@
-"""AOT compiles of the main-path Pallas kernels for a described TPU v5e.
+"""AOT compiles of the Pallas kernels for a described TPU v5e: the main
+path's (attention, triangle, OPM) and the row-wise kernels (softmax, layer
+norm, element-wise) that a plan naming their ``pallas`` leg runs.
 
 Interpret mode cannot see the TPU lowering's block-shape (8, 128) rule,
 scoped-VMEM limits or Mosaic's layout support; these compiles can, at the
