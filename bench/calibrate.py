@@ -6,7 +6,9 @@
 
 For each seed, each variant's checked units (train steps, or sampled folds
 of a short window) are compared with one reference run of that seed, and one
-JSON line per (seed, variant) gives the numbers ``check`` computes. Variants:
+JSON line per (seed, variant) gives the numbers ``check`` computes and
+whether they pass the cell's committed limits (``window_compiles`` aside,
+which a run of the benchmark counts). Variants:
 ``program`` (the sound program), ``control`` (the reference in float8 in the
 program's place), and the faults of ``fastbench.faults`` planted under the
 program. The benchmark's own runs never run this.
@@ -49,6 +51,8 @@ def main(argv=None) -> int:
     mode = cell.traffic["mode"]
     fp8 = reference.Numerics("fp8")
     counter = runtime.CompileCounter()
+    limits = {k: v for k, v in cell.check["limits"].items()
+              if k != "window_compiles"}
 
     def train_system(variant):
         if variant == "program":
@@ -103,19 +107,20 @@ def main(argv=None) -> int:
                    for b, _ in prog]
             ref = fold.reference_folds(dims, wkey, feed, idx, jax.devices()[0],
                                        reference.FP32)
-            if "control" in got:
-                ctl = fold.reference_folds(dims, wkey, feed, idx,
-                                           jax.devices()[0], fp8)
-                got["control"] = (None, sorted(ctl.items()), 0.0)
         ref_s = time.perf_counter() - t0
         for variant, (out, prog, phase_s) in got.items():
             if mode == "train":
                 numbers = check.train_numbers(prog, ref)
+            elif variant == "control":
+                numbers = check.worst(list(fold.control_numbers(
+                    dims, wkey, feed, ref, jax.devices()[0]).values()))
             else:
                 numbers = check.worst([check.fold_numbers(o, ref[b], feed[b])
                                        for b, o in prog])
+            ok, _ = check.judge(numbers, limits)
             print(json.dumps({
                 "seed": seed, "variant": variant, "numbers": numbers,
+                "correct_under_limits": ok,
                 "phase_s": phase_s, "reference_s": ref_s,
                 "setup_s": out.setup_s if out else None,
                 "per_unit_s": out.per_unit_s if out else None,

@@ -55,6 +55,15 @@ def reference_folds(dims, wkey, feed, indices, device, nx) -> dict:
     return out
 
 
+def control_numbers(dims, wkey, feed, ref: dict, device) -> dict:
+    """The control's fold numbers for each batch of ``ref`` (feed index ->
+    the reference's fold): the reference with every matrix product's
+    operands rounded through float8 e4m3, in the program's place."""
+    ctl = reference_folds(dims, wkey, feed, list(ref), device,
+                          reference.Numerics("fp8"))
+    return {b: check.fold_numbers(ctl[b], ref[b], feed[b]) for b in ref}
+
+
 def run(ctx, system=program_system) -> Outcome:
     out, checked, inputs = program_phase(ctx, system)
     dims, wkey, feed = inputs

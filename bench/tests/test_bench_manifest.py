@@ -87,3 +87,18 @@ def test_config_files_are_under_paths_and_distinct():
             json.load(fh)
     used = {w["config"] for w in B["workloads"]}
     assert used == {c["name"] for c in B["configs"]}
+
+
+CELL_DATA = sorted((kind, f[:-len(".json")])
+                   for kind in ("traffic", "workloads")
+                   for f in os.listdir(os.path.join(BENCH, kind))
+                   if f.endswith(".json"))
+
+
+@pytest.mark.parametrize("kind,name", CELL_DATA,
+                         ids=[f"{k}/{n}" for k, n in CELL_DATA])
+def test_every_cell_data_file_is_named_by_a_cell(kind, name):
+    # a traffic mix or a cell's limits that no cell names goes stale
+    # against the configuration it was written for
+    key = {"traffic": "traffic", "workloads": "name"}[kind]
+    assert name in {w[key] for w in B["workloads"]}

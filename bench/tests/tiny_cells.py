@@ -1,7 +1,7 @@
-"""The benchmark's cells cut to a size a test run holds on the CPU, and a
-runner of the training mode that skips the harness's look for a chip."""
+"""The benchmark's cells cut to a size a test run holds on the CPU, and
+runners of the training and fold modes that skip the harness's look for a
+chip."""
 import dataclasses
-import json
 import os
 import sys
 import time
@@ -53,17 +53,48 @@ def broken_train(fault):
     return system
 
 
-def tiny_from_files(name: str, config: str, traffic: str, chips: int):
-    """A cell that ``BENCHMARK.json`` does not list yet, from its
-    configuration and traffic files, at TINY widths, with no limits."""
-    root = manifest.BENCH
-    with open(os.path.join(root, "configs", config + ".json")) as f:
-        cfg = json.load(f)
-    with open(os.path.join(root, "traffic", traffic + ".json")) as f:
-        mix = json.load(f)
-    return manifest.Cell(
-        name=name, config=dict(cfg, **TINY),
-        traffic=dict(mix, n_res=32, n_seq=16, real_res=[25, 31],
-                     real_seq=[10, 15]),
-        check={"limits": {}}, chips=chips, end_to_end={}, per_layer={},
-        metric_files={})
+def run_fold(cell, system):
+    """One fold run of ``system`` on the CPU, on as many devices as the
+    cell asks for: (correct, checks)."""
+    import jax
+
+    from fastbench.modes import RunContext, fold
+
+    ctx = RunContext(seed=SEED, seconds=0.3, trace=False, cell=cell,
+                     devices=jax.devices()[:cell.chips],
+                     t0=time.perf_counter(),
+                     counter=runtime.CompileCounter())
+    out = fold.run(ctx, system=system)
+    ok, checks = check.judge(out.numbers, cell.check["limits"])
+    return ok and out.failed == 0, checks
+
+
+def broken_fold(fault):
+    """The program's fold system with ``fault`` wrapped round the function
+    that compiles the fold."""
+    from fastbench.modes import fold
+
+    def system(cfg, mesh):
+        compile_fold, check_layout = fold.program_system(cfg, mesh)
+        return fault(compile_fold), check_layout
+    return system
+
+
+def control_fold(cell) -> dict:
+    """The float8 control's fold numbers at ``cell``'s size on one CPU
+    device, the reference in float8 against the float32 reference: the
+    least of each number over the batches of the run's feed, whichever of
+    them a run samples."""
+    import jax
+
+    from fastbench import data, reference
+    from fastbench.modes import fold
+
+    dims = reference.Dims.from_config(cell.config)
+    wkey = jax.random.PRNGKey(data.jax_seed(SEED, fold.WEIGHT_SALT))
+    feed = data.feed(SEED, cell.traffic)
+    dev = jax.devices()[0]
+    ref = fold.reference_folds(dims, wkey, feed, range(len(feed)), dev,
+                               reference.FP32)
+    each = list(fold.control_numbers(dims, wkey, feed, ref, dev).values())
+    return {k: min(n[k] for n in each) for k in each[0]}
