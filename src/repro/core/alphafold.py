@@ -27,6 +27,7 @@ from repro.exec.plan import current_plan
 from repro.core.evoformer import (
     EvoformerConfig,
     evoformer_stack,
+    extra_msa_stack,
     init_evoformer_stack,
 )
 from repro.core.losses import N_DIST_BINS, N_MSA_TOK, alphafold_loss
@@ -41,6 +42,9 @@ from repro.memory.autochunk import resolve_evoformer_config
 
 N_AA = 21
 RELPOS_K = 32
+# Extra-MSA features (SI Alg. 2 line 13): the MSA one-hot, has_deletion and
+# deletion_value.
+N_EXTRA_FEAT = N_MSA_TOK + 2
 
 
 @dataclass(frozen=True)
@@ -50,6 +54,11 @@ class AlphaFoldConfig:
     n_recycle: int = 3          # extra passes (total passes = n_recycle + 1)
     recycle_bins: int = 15
     compute_dtype: Any = jnp.bfloat16
+    # The extra-MSA stack (SI Alg. 18) and its embedding: a block config
+    # with ``global_column`` set and the trunk's d_pair, run over the
+    # batch's ``extra_msa`` rows before the trunk. None: off (no extra
+    # parameters, inputs or work).
+    extra_msa: EvoformerConfig | None = None
 
     @property
     def d_msa(self):
@@ -63,7 +72,7 @@ class AlphaFoldConfig:
 def init_alphafold(key, cfg: AlphaFoldConfig) -> Params:
     ks = iter(jax.random.split(key, 16))
     d_m, d_z = cfg.d_msa, cfg.d_pair
-    return {
+    params = {
         "msa_embed": init_dense(next(ks), N_MSA_TOK, d_m, bias=True),
         "target_embed_m": init_dense(next(ks), N_AA, d_m, bias=True),
         "left_embed": init_dense(next(ks), N_AA, d_z, bias=True),
@@ -80,6 +89,12 @@ def init_alphafold(key, cfg: AlphaFoldConfig) -> Params:
         "msa_head": init_dense(next(ks), d_m, N_MSA_TOK, bias=True),
         "dist_head": init_dense(next(ks), d_z, N_DIST_BINS, bias=True),
     }
+    if cfg.extra_msa is not None:
+        params["extra_msa_embed"] = init_dense(
+            next(ks), N_EXTRA_FEAT, cfg.extra_msa.d_msa, bias=True)
+        params["extra_msa_stack"] = init_evoformer_stack(next(ks),
+                                                         cfg.extra_msa)
+    return params
 
 
 def embed_inputs(params, batch, cfg: AlphaFoldConfig):
@@ -99,6 +114,18 @@ def embed_inputs(params, batch, cfg: AlphaFoldConfig):
     pair = pair + dense(params["relpos_embed"],
                         jax.nn.one_hot(rel, 2 * RELPOS_K + 1, dtype=dt))
     return msa_rep, pair
+
+
+def embed_extra_msa(params, batch, cfg: AlphaFoldConfig):
+    """batch: extra_msa (B, s_e, r) int, extra_has_deletion and
+    extra_deletion_value (B, s_e, r) float -> (B, s_e, r, c_e)."""
+    dt = cfg.compute_dtype
+    feat = jnp.concatenate([
+        jax.nn.one_hot(batch["extra_msa"], N_MSA_TOK, dtype=dt),
+        batch["extra_has_deletion"][..., None].astype(dt),
+        batch["extra_deletion_value"][..., None].astype(dt),
+    ], axis=-1)
+    return dense(params["extra_msa_embed"], feat)
 
 
 def embed_recycle(params, msa, pair, prev, cfg: AlphaFoldConfig):
@@ -141,6 +168,16 @@ def alphafold_iteration(params, batch, prev, cfg: AlphaFoldConfig, *,
 
     seq_mask = batch["seq_mask"]
     pair_mask = seq_mask[:, :, None] * seq_mask[:, None, :]
+    if cfg.extra_msa is not None:
+        # SI Alg. 2 lines 13-14: the extra MSA updates the pair, then goes.
+        with jax.named_scope("alphafold.extra_msa_embed"):
+            extra = embed_extra_msa(params, batch, cfg).astype(dt)
+        with jax.named_scope("alphafold.extra_msa_stack"):
+            pair = extra_msa_stack(
+                params["extra_msa_stack"], extra, pair,
+                batch["extra_msa_mask"], seq_mask, pair_mask, dist=dist,
+                cfg=cfg.extra_msa, train=train,
+                rng=None if rng is None else jax.random.fold_in(rng, 1))
     msa, pair = evoformer_stack(
         params["evoformer"], msa, pair, batch["msa_mask"], seq_mask, pair_mask,
         dist=dist, cfg=cfg.evoformer, rng=rng, train=train,
@@ -182,16 +219,25 @@ def alphafold_forward(params, batch, cfg: AlphaFoldConfig, *,
     plan = current_plan()
     if dist is None:
         dist = plan.parallel.make_dist()
-    evo_cfg = plan.memory.apply(cfg.evoformer)
     b, s, r = batch["msa"].shape
-    # AutoChunk (trace-time, static shapes): fill chunk knobs left at 0 from
-    # the HBM budget instead of hand-set constants. budget_bytes=None lets
-    # the planner resolve the plan's MemoryPolicy budget itself (one path).
-    evo_cfg = resolve_evoformer_config(
-        evo_cfg, batch=b, n_seq=s, n_res=r,
-        dap=getattr(dist, "axis_size", 1), budget_bytes=hbm_budget)
+
+    def resolve(evo_cfg, n_seq):
+        # AutoChunk (trace-time, static shapes): fill chunk knobs left at 0
+        # from the HBM budget instead of hand-set constants. budget_bytes=
+        # None lets the planner resolve the plan's MemoryPolicy budget
+        # itself (one path).
+        return resolve_evoformer_config(
+            plan.memory.apply(evo_cfg), batch=b, n_seq=n_seq, n_res=r,
+            dap=getattr(dist, "axis_size", 1), budget_bytes=hbm_budget)
+
+    evo_cfg = resolve(cfg.evoformer, s)
     if evo_cfg is not cfg.evoformer:
         cfg = dataclasses.replace(cfg, evoformer=evo_cfg)
+    if cfg.extra_msa is not None:
+        # The extra stack's shapes differ (5120 rows at width 64 against
+        # the trunk's 512 at 256), so its knobs are planned on their own.
+        cfg = dataclasses.replace(cfg, extra_msa=resolve(
+            cfg.extra_msa, batch["extra_msa"].shape[1]))
     d_m, d_z = cfg.d_msa, cfg.d_pair
     if n_recycle is None:
         n_recycle = cfg.n_recycle

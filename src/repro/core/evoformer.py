@@ -67,6 +67,10 @@ class EvoformerConfig:
     msa_heads: int = 8
     pair_heads: int = 4
     head_dim: int = 32
+    # The extra-MSA block variant: MSA column attention is global attention
+    # (SI Alg. 19), one mean query per column against one key and one value
+    # head shared by all heads (``msa_col_global_attention``).
+    global_column: bool = False
     opm_dim: int = 32
     tri_mult_dim: int = 128
     transition_factor: int = 4
@@ -109,6 +113,13 @@ class EvoformerConfig:
     # knobs are always respected.
     auto_chunk: bool = True
 
+    @property
+    def msa_head_dim(self) -> int:
+        """Head width of the two MSA attention sites, ``d_msa / msa_heads``
+        as in AlphaFold-2: 32 in the trunk (256 / 8), 8 in the extra-MSA
+        stack (64 / 8, SI Alg. 18), whose pair side keeps ``head_dim``."""
+        return self.d_msa // self.msa_heads
+
 
 # ---------------------------------------------------------------------------
 # Init
@@ -118,12 +129,13 @@ def init_evoformer_block(key, cfg: EvoformerConfig) -> Params:
     ks = iter(jax.random.split(key, 24))
     d_m, d_z = cfg.d_msa, cfg.d_pair
     hm, hz, hd = cfg.msa_heads, cfg.pair_heads, cfg.head_dim
+    hd_m = cfg.msa_head_dim
     c_mult = cfg.tri_mult_dim
 
-    def attn(d_in, heads, d_out):
+    def attn(d_in, heads, d_out, head_dim=hd):
         return init_attention(
-            next(ks), d_in, heads, heads, hd, gating=True, out_bias=True,
-            d_out=d_out,
+            next(ks), d_in, heads, heads, head_dim, gating=True,
+            out_bias=True, d_out=d_out,
         )
 
     def tri_mult():
@@ -150,9 +162,12 @@ def init_evoformer_block(key, cfg: EvoformerConfig) -> Params:
             "ln_m": init_layer_norm(d_m),
             "ln_z": init_layer_norm(d_z),
             "bias": init_dense(next(ks), d_z, hm, bias=False),
-            "attn": attn(d_m, hm, d_m),
+            "attn": attn(d_m, hm, d_m, hd_m),
         },
-        "msa_col": {"ln": init_layer_norm(d_m), "attn": attn(d_m, hm, d_m)},
+        "msa_col": {"ln": init_layer_norm(d_m),
+                    "attn": (init_global_attention(next(ks), d_m, hm, hd_m)
+                             if cfg.global_column
+                             else attn(d_m, hm, d_m, hd_m))},
         "msa_trans": {"ln": init_layer_norm(d_m),
                       "mlp": init_transition(next(ks), d_m, cfg.transition_factor)},
         "opm": {
@@ -167,6 +182,24 @@ def init_evoformer_block(key, cfg: EvoformerConfig) -> Params:
         "tri_attn_end": tri_attn(),
         "pair_trans": {"ln": init_layer_norm(d_z),
                        "mlp": init_transition(next(ks), d_z, cfg.transition_factor)},
+    }
+
+
+def init_global_attention(key, d_in: int, heads: int, head_dim: int) -> Params:
+    """Global column attention (SI Alg. 19): a per-head query projection of
+    the column's mean, one key and one value head shared by all heads
+    (merged), the per-element gate (bias 1: gates start open) and the output
+    projection. The gate and output keys are ``init_attention``'s, so
+    ``output_proj`` applies them."""
+    kq, kkv, kg, ko = jax.random.split(key, 4)
+    gate = init_dense(kg, d_in, heads * head_dim, bias=True)
+    gate["b"] = jnp.ones_like(gate["b"])
+    return {
+        "wq": init_dense(kq, d_in, heads * head_dim, bias=False),
+        "wkv": init_dense(kkv, d_in, 2 * head_dim, bias=False),
+        "wg": gate,
+        "wo": init_dense(ko, heads * head_dim, d_in, bias=True,
+                         zero_init=True),
     }
 
 
@@ -281,7 +314,7 @@ def msa_row_attention(p, msa, pair, seq_mask, dist, cfg: EvoformerConfig):
     """msa (B, s/N, r, Hm) [s-shard]; pair (B, i/N, j, Hz) [i-shard];
     seq_mask (B, r) replicated."""
     b, s_loc, r, _ = msa.shape
-    dims = AttnDims(cfg.msa_heads, cfg.msa_heads, cfg.head_dim)
+    dims = AttnDims(cfg.msa_heads, cfg.msa_heads, cfg.msa_head_dim)
     # Pair bias: project local pair rows -> (B, i/N, j, H) -> gather rows.
     z_n = layer_norm(p["ln_z"], pair)
     bias_loc = dense(p["bias"], z_n)                      # (B, i/N, j, H)
@@ -300,7 +333,7 @@ def msa_row_attention(p, msa, pair, seq_mask, dist, cfg: EvoformerConfig):
 def msa_col_attention(p, msa, msa_mask, dist, cfg: EvoformerConfig):
     """msa (B, s, r/N, Hm) [r-shard]; msa_mask (B, s, r/N)."""
     b, s, r_loc, _ = msa.shape
-    dims = AttnDims(cfg.msa_heads, cfg.msa_heads, cfg.head_dim)
+    dims = AttnDims(cfg.msa_heads, cfg.msa_heads, cfg.msa_head_dim)
     m_n = layer_norm(p["ln"], msa)
     x = m_n.transpose(0, 2, 1, 3)                  # (B, r/N, s, d)
     key_mask = msa_mask.transpose(0, 2, 1)         # (B, r/N, s)
@@ -308,6 +341,31 @@ def msa_col_attention(p, msa, msa_mask, dist, cfg: EvoformerConfig):
                            dist=dist, chunk=cfg.inference_chunk,
                            kv_tile=cfg.attn_kv_tile)
     return out.transpose(0, 2, 1, 3)
+
+
+def msa_col_global_attention(p, msa, msa_mask, dist, cfg: EvoformerConfig):
+    """Global column attention (SI Alg. 19) on msa (B, s, r/N, c) [r-shard];
+    msa_mask (B, s, r/N). Each column's query is the masked mean of its
+    LN'ed rows, projected per head; keys and values are one head shared by
+    all heads, so a column costs O(s), not the O(s²) of column attention
+    (``ops.global_attention``). The sigmoid gate is per element, so the
+    column's one context is gated row by row before the output projection.
+    Columns are independent: under DAP's r-shard nothing is exchanged."""
+    del dist
+    h, hd = cfg.msa_heads, cfg.msa_head_dim
+    x = layer_norm(p["ln"], msa).transpose(0, 2, 1, 3)      # (B, r/N, s, c)
+    mask = msa_mask.transpose(0, 2, 1).astype(jnp.float32)  # (B, r/N, s)
+    pa = p["attn"]
+    mean = (jnp.sum(x.astype(jnp.float32) * mask[..., None], axis=2)
+            / (jnp.sum(mask, axis=-1, keepdims=True) + 1e-10))
+    q = dense(pa["wq"], mean.astype(x.dtype))
+    q = q.reshape(q.shape[:-1] + (h, hd))                   # (B, r/N, H, hd)
+    k, v = jnp.split(dense(pa["wkv"], x), 2, axis=-1)       # (B, r/N, s, hd)
+    ctx = ops.global_attention(q, k, v,
+                               mask=jnp.where(mask > 0, 0.0, NEG_INF),
+                               scale=1.0 / (hd ** 0.5))
+    ctx = jnp.broadcast_to(ctx[:, :, None], x.shape[:3] + (h, hd))
+    return output_proj(pa, ctx, x_for_gate=x).transpose(0, 2, 1, 3)
 
 
 def msa_transition(p, msa):
@@ -514,13 +572,15 @@ def evoformer_block(
                                 cfg)
         msa = _residual_add(upd, msa, cfg.dropout_msa, rngs[0], 2, train)
 
-    with jax.named_scope("evoformer.msa_col_attention"):
+    col = (msa_col_global_attention if cfg.global_column
+           else msa_col_attention)
+    with jax.named_scope("evoformer." + col.__name__):
         # all_to_all #1: s-shard -> r-shard.
         msa = dist.all_to_all(msa, split_axis=2, concat_axis=1)
         msa = dist.constrain(msa, ("b", None, "m", None))
         msa_mask_r = dist.all_to_all(msa_mask, split_axis=2, concat_axis=1)
 
-        upd = msa_col_attention(params["msa_col"], msa, msa_mask_r, dist, cfg)
+        upd = col(params["msa_col"], msa, msa_mask_r, dist, cfg)
         msa = _residual_add(upd, msa, 0.0, None, 0, train)
     with jax.named_scope("evoformer.msa_transition"):
         msa = _residual_add(msa_transition(params["msa_trans"], msa), msa,
@@ -624,3 +684,29 @@ def evoformer_stack(
         body = jax.checkpoint(body, policy=policy)
     (msa, pair), _ = jax.lax.scan(body, (msa, pair), (params_stacked, rngs))
     return msa, pair
+
+
+def extra_msa_stack(
+    params_stacked: Params,
+    extra_msa: jax.Array,
+    pair: jax.Array,
+    extra_mask: jax.Array,
+    seq_mask: jax.Array,
+    pair_mask_loc: jax.Array,
+    *,
+    dist=None,
+    cfg: EvoformerConfig,
+    rng=None,
+    train: bool = False,
+):
+    """The extra-MSA stack (SI Alg. 18): ``cfg.n_blocks`` blocks of the
+    global-column variant (``cfg.global_column``) over the embedded extra
+    MSA (B, s_extra, r, c). Only the pair leaves it; the extra MSA is
+    dropped."""
+    if not cfg.global_column:
+        raise ValueError("the extra-MSA stack runs the global-column block "
+                         "variant: set EvoformerConfig.global_column")
+    _, pair = evoformer_stack(params_stacked, extra_msa, pair, extra_mask,
+                              seq_mask, pair_mask_loc, dist=dist, cfg=cfg,
+                              rng=rng, train=train)
+    return pair

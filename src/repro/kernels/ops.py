@@ -529,6 +529,46 @@ def fused_attention(
 
 
 # ---------------------------------------------------------------------------
+# global attention (one query per group against a shared key/value head)
+# ---------------------------------------------------------------------------
+
+
+@_scoped("global_attention")
+def global_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    mask: jax.Array | None = None,
+    scale: float | None = None,
+) -> jax.Array:
+    """softmax(scale * q k^T + mask) @ v with one query per head and one key
+    and one value head shared by all heads — the core of the extra-MSA
+    stack's global column attention (Jumper et al. 2021, SI Alg. 19).
+
+    q (..., H, D); k, v (..., S, D); mask (..., S) additive fp32 (finite,
+    ~-1e9 where masked). Returns (..., H, D) in q's dtype. The leading dims
+    are never merged, so mesh-sharded ones stay sharded.
+
+    The scores are (..., H, S): no flash tiling pays at that size, so there
+    is no kernel. Every leg of ``KernelPolicy.attention`` runs this XLA leg
+    (products in the input dtype, fp32 accumulation and softmax); the
+    'oracle' leg (``enabled=False``) runs ``ref.global_attention_ref``.
+    Plain autodiff gives the backward."""
+    if scale is None:
+        scale = 1.0 / (q.shape[-1] ** 0.5)
+    if kernel_leg("attention") == "oracle":
+        return ref.global_attention_ref(q, k, v, mask, scale)
+    s = jnp.einsum("...hd,...sd->...hs", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    if mask is not None:
+        s = s + mask.astype(jnp.float32)[..., None, :]
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("...hs,...sd->...hd", p, v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
 # fused triangle multiplicative update + outer-product-mean (pair stack)
 # ---------------------------------------------------------------------------
 

@@ -114,6 +114,26 @@ def attention_bwd_ref(
     return tuple(grads)
 
 
+def global_attention_ref(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    mask: jax.Array | None = None,
+    scale: float = 1.0,
+) -> jax.Array:
+    """Oracle for ops.global_attention, in float32 throughout.
+
+    q: (..., H, D) one query per head; k, v: (..., S, D) one key and one
+    value head shared by all heads; mask: (..., S) additive, broadcast over
+    H. Returns (..., H, D) in q.dtype."""
+    f32 = jnp.float32
+    s = jnp.einsum("...hd,...sd->...hs", q.astype(f32), k.astype(f32)) * scale
+    if mask is not None:
+        s = s + mask.astype(f32)[..., None, :]
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("...hs,...sd->...hd", p, v.astype(f32)).astype(q.dtype)
+
+
 def triangle_mult_ref(
     a_lin: jax.Array,
     ga: jax.Array,

@@ -47,6 +47,7 @@ from repro.kernels.ops import (
     _DEFAULT_OPM_TILE,
     _DEFAULT_TRI_TILE,
 )
+from repro.kernels.flash_attention import LANE, _pad_to
 from repro.launch.mesh import HBM_BYTES
 
 
@@ -168,6 +169,7 @@ def evoformer_peak_bytes(
     n_res: int,
     dap: int = 1,
     fused: bool = True,
+    staged: bool = False,
     inference_chunk: int = 0,
     opm_chunk: int = 0,
     attn_kv_tile: int = 0,
@@ -177,7 +179,12 @@ def evoformer_peak_bytes(
     """Dominant per-device activation terms (bytes) of one Evoformer block.
 
     cfg: EvoformerConfig (duck-typed: d_msa, d_pair, msa_heads, pair_heads,
-    head_dim, opm_dim, tri_mult_dim, compute_dtype). Returns a dict of named
+    head_dim, opm_dim, tri_mult_dim, transition_factor, compute_dtype).
+    ``staged``: the fused leg is the Pallas kernel, which stages q, k, v and
+    its output with each head padded to 128 lanes (``ops._attn_tiles``), so
+    every attention call is counted at that width: 4x the bytes of a 32-wide
+    head, 16x those of the extra-MSA stack's 8-wide heads over 5120 rows.
+    The XLA leg stages the heads at their width. Returns a dict of named
     terms; ``sum(values())`` is the modeled peak.
     """
     dt = jnp.dtype(cfg.compute_dtype).itemsize
@@ -199,18 +206,27 @@ def evoformer_peak_bytes(
     }
     # Attention: MSA row (groups = local MSA rows) and triangle (groups =
     # local pair rows) phases don't overlap — take the max.
+    def width(head_dim):
+        return _pad_to(head_dim, LANE) if fused and staged else head_dim
+
     attn_row = attention_transient_bytes(
         batch * _eff_div_chunk(s_loc, inference_chunk), cfg.msa_heads, n_res,
-        cfg.head_dim, kv_tile=attn_kv_tile, fused=fused, dtype_bytes=dt)
+        width(cfg.d_msa // cfg.msa_heads), kv_tile=attn_kv_tile, fused=fused,
+        dtype_bytes=dt)
     attn_tri = attention_transient_bytes(
         batch * _eff_div_chunk(r_loc, inference_chunk), cfg.pair_heads, n_res,
-        cfg.head_dim, kv_tile=attn_kv_tile, fused=fused, dtype_bytes=dt)
+        width(cfg.head_dim), kv_tile=attn_kv_tile, fused=fused,
+        dtype_bytes=dt)
     terms["attention"] = max(attn_row, attn_tri)
     # Outer Product Mean: gathered right projection + the fp32 outer-product
     # block (opm_s_tile-bounded when fused, opm_chunk scan otherwise).
     terms["opm"] = batch * opm_transient_bytes(
         r_loc, n_res, n_seq, cfg.opm_dim, tile=opm_s_tile,
         opm_chunk=opm_chunk, fused=fused, dtype_bytes=dt)
+    # The MSA transition's (B, s, r, f·c) hidden: 0.67 GB over the extra
+    # stack's 5120 rows at r 256, c 64.
+    terms["msa_transition"] = (batch * s_loc * n_res
+                               * cfg.transition_factor * cfg.d_msa * dt)
     return terms
 
 
@@ -298,6 +314,7 @@ def plan_evoformer_chunks(
     budget_bytes: int = HBM_BYTES,
     dap: int = 1,
     fused: bool = True,
+    staged: bool = False,
 ) -> ChunkPlan:
     """Pick the least-chunked (inference_chunk, opm_chunk, attn_kv_tile)
     whose modeled peak fits ``budget_bytes``. Nonzero knobs already set on
@@ -317,7 +334,7 @@ def plan_evoformer_chunks(
     def est(ic, oc, kt, tt, ot) -> int:
         return sum(evoformer_peak_bytes(
             cfg, batch=batch, n_seq=n_seq, n_res=n_res, dap=dap, fused=fused,
-            inference_chunk=ic, opm_chunk=oc, attn_kv_tile=kt,
+            staged=staged, inference_chunk=ic, opm_chunk=oc, attn_kv_tile=kt,
             tri_k_tile=tt, opm_s_tile=ot).values())
 
     def serialization_cost(ic, oc, kt, tt, ot):
@@ -389,11 +406,12 @@ def resolve_evoformer_config(
     from repro.kernels import ops
 
     fused = ops.fused_attention_supported(
-        (batch, n_seq, n_res, cfg.msa_heads, cfg.head_dim), kv_len=n_res,
-        dtype=cfg.compute_dtype)
+        (batch, n_seq, n_res, cfg.msa_heads, cfg.d_msa // cfg.msa_heads),
+        kv_len=n_res, dtype=cfg.compute_dtype)
     plan = plan_evoformer_chunks(
         cfg, batch=batch, n_seq=n_seq, n_res=n_res,
-        budget_bytes=budget_bytes, dap=dap, fused=fused)
+        budget_bytes=budget_bytes, dap=dap, fused=fused,
+        staged=ops._use_pallas(ops.kernel_leg("attention")))
     return apply_plan(cfg, plan)
 
 

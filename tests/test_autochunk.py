@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.configs.alphafold import FULL, SMOKE
+from repro.exec.plan import ExecutionPlan, KernelPolicy, use_plan
 from repro.launch.mesh import HBM_BYTES
 from repro.memory.autochunk import (
     ChunkPlan,
@@ -151,6 +152,83 @@ def test_alphafold_forward_resolves_chunks():
                                   hbm_budget=tight)
     np.testing.assert_allclose(np.asarray(out_auto["coords"]),
                                np.asarray(out_chunk["coords"]), atol=2e-4)
+
+
+# AlphaFold-2 model_3's extra-MSA block: 8 heads of 8 on a 64-wide MSA, the
+# trunk's pair side, global column attention.
+EXTRA_FULL = dataclasses.replace(FULL.evoformer, d_msa=64, msa_heads=8, n_blocks=4,
+                                 global_column=True)
+
+
+def test_extra_stack_counts_row_attention_at_the_staged_width():
+    """The Pallas kernel stages q, k, v and its output at 128 lanes: at the
+    extra stack's 8-wide heads the planner counts them 16 times over, at
+    the trunk's 32-wide heads 4 times; the XLA leg stages them unpadded."""
+    terms = evoformer_peak_bytes(EXTRA_FULL, batch=1, n_seq=5120, n_res=256,
+                                 staged=True)
+    assert terms["attention"] == attention_transient_bytes(5120, 8, 256, 128)
+    assert terms["attention"] - attention_transient_bytes(5120, 8, 256, 8) \
+        == 4 * 5120 * 256 * 8 * (128 - 8) * 2
+    assert terms["msa_transition"] == 5120 * 256 * 4 * 64 * 2
+    unstaged = evoformer_peak_bytes(EXTRA_FULL, batch=1, n_seq=5120,
+                                    n_res=256)
+    assert unstaged["attention"] == attention_transient_bytes(5120, 8, 256, 8)
+    # the rule is the head width's, not the block variant's
+    trunk = evoformer_peak_bytes(FULL.evoformer, batch=1, n_seq=512,
+                                 n_res=256, staged=True)
+    assert trunk["attention"] == attention_transient_bytes(512, 8, 256, 128)
+    assert trunk["msa_transition"] == 512 * 256 * 4 * 256 * 2
+
+
+def test_extra_stack_is_planned_apart_from_the_trunk():
+    """At r 256 with model_3's 512 clusters and 5120 extra rows on the v5e
+    budget and the chip's kernel leg, the trunk runs unchunked while the
+    extra stack's row attention is chunked over rows, and the extra stack's
+    planned peak fits."""
+    with use_plan(ExecutionPlan(kernels=KernelPolicy(attention="pallas"))):
+        trunk = resolve_evoformer_config(FULL.evoformer, batch=1, n_seq=512,
+                                         n_res=256, budget_bytes=HBM_BYTES)
+        extra = resolve_evoformer_config(EXTRA_FULL, batch=1, n_seq=5120,
+                                         n_res=256, budget_bytes=HBM_BYTES)
+    assert trunk.inference_chunk == 0
+    assert 0 < extra.inference_chunk < 5120
+    assert 5120 % extra.inference_chunk == 0
+    plan = plan_evoformer_chunks(EXTRA_FULL, batch=1, n_seq=5120, n_res=256,
+                                 budget_bytes=HBM_BYTES, staged=True)
+    assert plan.fits and plan.est_bytes <= HBM_BYTES
+    assert plan.inference_chunk == extra.inference_chunk
+    # unchunked, the staging alone would not fit
+    assert _total(EXTRA_FULL, batch=1, n_seq=5120, n_res=256,
+                  staged=True) > HBM_BYTES
+
+
+def test_alphafold_forward_resolves_the_extra_stack_on_its_own(monkeypatch):
+    """alphafold_forward plans each stack at its own depth."""
+    import repro.core.alphafold as af
+    from repro.data import protein_batches
+
+    seen = []
+    real = af.resolve_evoformer_config
+
+    def spy(cfg, **kw):
+        seen.append((cfg.global_column, kw["n_seq"]))
+        return real(cfg, **kw)
+
+    monkeypatch.setattr(af, "resolve_evoformer_config", spy)
+    extra = dataclasses.replace(SMOKE.evoformer, d_msa=16, msa_heads=2, n_blocks=1,
+                                global_column=True)
+    cfg = dataclasses.replace(SMOKE, extra_msa=extra)
+    pb = next(protein_batches(batch=1, n_seq=8, n_res=16, seed=0,
+                              n_extra_seq=24))
+    batch = {k: jnp.asarray(getattr(pb, k)) for k in
+             ("msa", "msa_mask", "residue_index", "aatype", "seq_mask",
+              "extra_msa", "extra_msa_mask", "extra_has_deletion",
+              "extra_deletion_value")}
+    params = jax.eval_shape(lambda k: af.init_alphafold(k, cfg),
+                            jax.random.PRNGKey(0))
+    jax.eval_shape(lambda p, b: af.alphafold_forward(p, b, cfg, n_recycle=0),
+                   params, batch)
+    assert seen == [(False, 8), (True, 24)]
 
 
 def test_decoder_plan_keeps_config_when_it_fits():

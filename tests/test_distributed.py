@@ -50,6 +50,38 @@ print("DAP_OK", n_a2a, n_ag)
 """
 
 
+# The extra-MSA stack's block variant (global column attention) under DAP:
+# each column's global attention is local to its r-shard, so DAP reproduces
+# the single-device stack. Ragged extra rows; every weight perturbed so no
+# zero-initialised projection hides a path.
+DAP_EXTRA_SCRIPT = r"""
+import numpy as np, jax, jax.numpy as jnp
+from repro.core.evoformer import EvoformerConfig, init_evoformer_stack, evoformer_stack
+from repro.core.dap import dap_evoformer_stack, shard_dap_inputs
+cfg = EvoformerConfig(d_msa=16, d_pair=16, msa_heads=2, pair_heads=2, head_dim=8, opm_dim=8, tri_mult_dim=16,
+                      n_blocks=2, global_column=True)
+params = init_evoformer_stack(jax.random.PRNGKey(0), cfg)
+leaves, tree = jax.tree.flatten(params)
+keys = jax.random.split(jax.random.PRNGKey(3), len(leaves))
+params = jax.tree.unflatten(tree, [x + 0.2 * jax.random.normal(k, x.shape)
+                                   for x, k in zip(leaves, keys)])
+B,s,r = 2,12,8
+msa = jax.random.normal(jax.random.PRNGKey(1),(B,s,r,cfg.d_msa))
+pair = jax.random.normal(jax.random.PRNGKey(2),(B,r,r,cfg.d_pair))
+rows = (jnp.arange(s) < 9).astype(jnp.float32)
+masks = (jnp.broadcast_to(rows[None, :, None], (B,s,r)), jnp.ones((B,r)),
+         jnp.ones((B,r,r)))
+m_ref, p_ref = evoformer_stack(params, msa, pair, *masks, cfg=cfg, remat=False)
+from repro.launch.mesh import _mesh
+mesh = _mesh((1,4), ("data","model"))
+fn = jax.jit(dap_evoformer_stack(mesh, cfg, remat=False))
+m_dap, p_dap = fn(params, *shard_dap_inputs(mesh, msa, pair, *masks))
+np.testing.assert_allclose(np.asarray(p_dap), np.asarray(p_ref), atol=1e-4)
+np.testing.assert_allclose(np.asarray(m_dap), np.asarray(m_ref), atol=1e-4)
+print("DAP_EXTRA_OK")
+"""
+
+
 TP_SCRIPT = r"""
 import re, numpy as np, jax, jax.numpy as jnp
 from repro.core.evoformer import EvoformerConfig, init_evoformer_stack, evoformer_stack
@@ -365,6 +397,10 @@ print("DUALITY_WINDOW_OK", rep)
 @pytest.mark.slow
 def test_dap_shard_map_equals_local_oracle():
     assert "DAP_OK" in run_sub(DAP_SCRIPT, devices=4)
+
+
+def test_dap_extra_msa_stack_equals_local():
+    assert "DAP_EXTRA_OK" in run_sub(DAP_EXTRA_SCRIPT, devices=4)
 
 
 @pytest.mark.slow

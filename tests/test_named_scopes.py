@@ -76,3 +76,42 @@ def test_train_step_names_families_and_submodules(leg):
     for scope in ("alphafold.embed", "alphafold.recycle", "alphafold.heads",
                   "alphafold.loss", "structure.module", "train.optimizer"):
         assert any(scope in n for n in names), scope
+
+
+def test_fold_names_the_extra_msa_stack():
+    """The extra-MSA stack's embedding and stack, its global column
+    attention and the ``global_attention`` family appear in the compiled
+    fold, and its blocks name their sub-modules inside the stack's scope."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench"))
+    from fastbench import scopes
+
+    extra = replace(CFG.evoformer, d_msa=16, msa_heads=2,
+                    global_column=True)
+    ff = FastFold(replace(CFG, extra_msa=extra), ExecutionPlan())
+    params = jax.eval_shape(ff.init, jax.random.PRNGKey(0))
+    pb = next(protein_batches(batch=1, n_seq=4, n_res=8, seed=0,
+                              n_extra_seq=6))
+    batch = {k: jnp.asarray(getattr(pb, k)) for k in
+             ("msa", "msa_mask", "residue_index", "aatype", "seq_mask",
+              "extra_msa", "extra_msa_mask", "extra_has_deletion",
+              "extra_deletion_value")}
+    names = _OP_NAME.findall(ff.lower("forward", params, batch).compile()
+                             .as_text())
+    for scope in ("alphafold.extra_msa_embed", "alphafold.extra_msa_stack",
+                  "evoformer.msa_col_global_attention",
+                  "ops.global_attention"):
+        assert any(scope in n for n in names), scope
+    inside = [n for n in names if "alphafold.extra_msa_stack" in n]
+    assert any("evoformer.msa_row_attention" in n for n in inside)
+    assert any("evoformer.outer_product_mean" in n for n in inside)
+    assert "global_attention" in {scopes.family(n) for n in inside}
+    # Every op of the global column attention lies inside the stack's scope,
+    # which the trace's reader counts. (The reducers' own regions carry a
+    # shorter path; they are never device ops of their own.)
+    ops = [n for n in names if n.startswith("jit(")]
+    assert not any("evoformer.msa_col_global_attention" in n for n in ops
+                   if "alphafold.extra_msa_stack" not in n)

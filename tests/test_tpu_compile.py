@@ -30,12 +30,15 @@ from repro.kernels.triangle import fused_opm_pallas, fused_triangle_pallas
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 
-# (N, S, H, bias, mask) of the Evoformer's attention sites at FULL widths,
-# n_res 256, n_seq 128: N is batch x group, S the attended length.
+# (N, S, H, D, bias, mask) of the Evoformer's attention sites at FULL
+# widths, n_res 256, n_seq 128: N is batch x group, S the attended length;
+# and the extra-MSA stack's row attention (model_3: 5120 rows, 8 heads of
+# 8), which the kernel stages at 128 lanes as every site.
 ATTENTION_SITES = {
-    "msa_row": (128, 256, 8, True, True),
-    "msa_col": (256, 128, 8, False, True),
-    "tri_attn": (256, 256, 4, True, True),
+    "msa_row": (128, 256, 8, 32, True, True),
+    "msa_col": (256, 128, 8, 32, False, True),
+    "tri_attn": (256, 256, 4, 32, True, True),
+    "extra_msa_row": (5120, 256, 8, 8, True, True),
 }
 HEAD_DIM = 32
 
@@ -77,14 +80,14 @@ def _assert_kernels(hlo: str, n: int):
 
 
 def _attention_shapes(site):
-    n, s, h, has_bias, has_mask = ATTENTION_SITES[site]
-    q_tile, kv_tile, d_pad = ops._attn_tiles(s, s, HEAD_DIM, 0)
+    n, s, h, d, has_bias, has_mask = ATTENTION_SITES[site]
+    q_tile, kv_tile, d_pad = ops._attn_tiles(s, s, d, 0)
     sq, skv = -(-s // q_tile) * q_tile, -(-s // kv_tile) * kv_tile
     q = ((n, h, sq, d_pad), BF16)
     kv = ((n, h, skv, d_pad), BF16)
     bias = ((1, h, sq, skv), BF16) if has_bias else None
     mask = ((n, 1, skv), F32) if has_mask else None
-    kw = dict(scale=HEAD_DIM ** -0.5, kv_len=s, q_tile=q_tile,
+    kw = dict(scale=d ** -0.5, kv_len=s, q_tile=q_tile,
               kv_tile=kv_tile, has_bias=has_bias, has_mask=has_mask,
               interpret=False)
     return q, kv, ((n, h, sq), F32), bias, mask, kw
