@@ -31,8 +31,9 @@ shapes) sends every site to its materialized jnp path, kept for A/B and
 diagnosis; ``KernelPolicy(triangle='oracle', opm='oracle')`` pins just the
 triangle/OPM ops. All LayerNorms go through the fused LN kernel; gating
 through bias+sigmoid+mul; residual adds through bias+dropout+add with the
-AlphaFold shared-axis dropout mask. QKV and left/right projections use
-merged GEMMs.
+AlphaFold shared-axis dropout mask. Left/right projections use merged
+GEMMs; QKV keeps one merged weight and runs one GEMM per output
+(``_project_heads``), the flash kernel's layout.
 
 Chunk knobs (``inference_chunk``, ``opm_chunk``, ``attn_kv_tile``,
 ``tri_k_tile``, ``opm_s_tile``) default to 0 = off/kernel-default; the
@@ -52,7 +53,7 @@ from repro.core.dist import LocalDist
 from repro.exec.plan import current_plan
 from repro.kernels import ops
 from repro.layers.attention import evoformer_attention, init_attention, AttnDims, \
-    project_qkv, output_proj
+    output_proj
 from repro.layers.mlp import init_transition, transition
 from repro.layers.norms import init_layer_norm, layer_norm
 from repro.layers.params import Params, dense, init_dense
@@ -225,6 +226,21 @@ def _residual_add(upd, residual, rate: float, rng, shared_axis: int,
     )
 
 
+def _project_heads(p_attn, x, dims: AttnDims):
+    """x (..., S, d) -> q (..., S, H, hd), k/v (..., S, KV, hd): one GEMM per
+    output on the merged ``wqkv`` weight's columns. The flash kernel reads
+    each of q, k and v whole as (..., S, H·hd), a free reshape of its own
+    GEMM's output; a slice of one merged output would be a copy pass over
+    all three (the weight's column slices are a few hundred KB here)."""
+    h, kv, hd = dims
+    cols = {name: jnp.split(a, [h * hd, (h + kv) * hd], axis=-1)
+            for name, a in p_attn["wqkv"].items()}          # w, and b if any
+    return tuple(
+        dense({name: a[i] for name, a in cols.items()}, x).reshape(
+            x.shape[:-1] + (heads, hd))
+        for i, heads in enumerate((h, kv, kv)))
+
+
 def _gated_attention(p_attn, x_n, bias, key_mask, dims: AttnDims,
                      dist=LocalDist(), chunk: int = 0, kv_tile: int = 0):
     """Group attention, kept 5D so (batch, group) dims never merge — merging
@@ -252,7 +268,7 @@ def _gated_attention(p_attn, x_n, bias, key_mask, dims: AttnDims,
     fallback only (trades latency for memory; DAP is the scalable answer).
     """
     def attend(x_c, mask_c):
-        q, k, v = project_qkv(p_attn, x_c, dims, compute_dtype=x_c.dtype)
+        q, k, v = _project_heads(p_attn, x_c, dims)
         hd = q.shape[-1]
         scale = 1.0 / (hd**0.5)
         mask = None
@@ -260,8 +276,12 @@ def _gated_attention(p_attn, x_n, bias, key_mask, dims: AttnDims,
         if bias_w is not None:
             # Duality-Async window: fence the gathered pair bias with the QKV
             # projection so the gather cannot sink past the independent GEMMs
-            # to its consumer below (core/duality.py).
-            bias_w, q = duality.overlap_window(bias_w, q)
+            # to its consumer below (core/duality.py). q passes the fence
+            # with its heads merged, (..., S, H·D): the flash kernel's
+            # layout, so the barrier hands it on without a relayout copy.
+            bias_w, q_m = duality.overlap_window(
+                bias_w, q.reshape(q.shape[:-2] + (-1,)))
+            q = q_m.reshape(q.shape)
         if mask_c is not None:
             mask = jnp.where(mask_c > 0, 0.0, NEG_INF).astype(jnp.float32)
         if (ops.fused_attention_supported(q.shape, kv_len=k.shape[2],

@@ -26,9 +26,13 @@ per-grid-cell loop, ~2x the jnp online-softmax path on CPU smoke shapes.
 
 Kernel contract (enforced/prepared by ops.fused_attention):
 
-  q, k, v : (N, H, S, D) with D already zero-padded to a 128-lane multiple
-            and S padded to the q/kv tile (zero rows — harmless: they attend
-            over the real KV range and are sliced off by the caller).
+Forward (``flash_attention_pallas``), on the model's own layout:
+
+  q, k, v : (N, S, H·D), the (N, S, H, D) the projection emits with its
+            heads merged (a free reshape: no transpose, no lane padding), S
+            padded to the q/kv tile only where it is not a multiple (zero
+            rows — harmless: they attend over the real KV range and are
+            sliced off by the caller).
   bias    : (B, H, Sq, Skv) additive, ``N % B == 0`` (each bias batch element
             is shared by N/B consecutive rows of q — the Evoformer pair bias
             shared across the MSA/group axis), or None.
@@ -39,18 +43,28 @@ Kernel contract (enforced/prepared by ops.fused_attention):
   kv_len  : true KV length before padding; padded columns are masked to
             ``NEG_INF`` in-kernel so they never win the max nor add to the sum.
 
-Returns ``out (N, H, Sq, D)`` in the input dtype and the fp32 log-sum-exp
-``lse (N, H, Sq)`` that the recompute backward in ops.py needs. Inside the
-kernels the per-row statistics (lse, delta) and the mask reduction travel as
+Returns ``out (N, Sq, H·D)`` in the input dtype and the fp32 log-sum-exp
+``lse (N, H, Sq)`` that the recompute backward needs. One grid step computes
+every head of a ``head_group`` (all heads at the Evoformer's widths) for one
+(row, q tile): static lane slices ``[h·D, (h+1)·D)`` of the blocks, the
+outputs concatenated along lanes, lse written as a (heads, q_tile) block of
+``(N, H/group, group, Sq)``. Grid ``(H/group, N, Sq/q_tile, Skv/kv_tile)``:
+with one KV tile (every site at n_res 256, n_seq 512) a shared pair bias
+stays resident across consecutive rows and k/v across a row's q tiles, and
+the softmax is taken in one pass; with several the fp32 (m, l, acc) state
+lives in VMEM scratch across the innermost KV sweep and the output is
+written on its last step.
+
+Backward (``flash_attention_bwd_pallas``), on the staged layout ops.py
+builds from the saved residuals: q, k, v, dO (N, H, S, D) with D zero-padded
+to a 128-lane multiple and S padded to the q/kv tile, one head per grid
+step. The per-row statistics (lse, delta) and the mask reduction travel as
 ``(N, H, 1, S)`` rows: a ``(1, 1, 1, tile)`` block obeys the (8, 128) rule
 where a ``(1, 1, tile)`` block of ``(N, H, S)`` does not, and a row costs at
 most an 8-sublane pad in HBM where a lane-broadcast column would cost 128x.
 
-Grid: ``(N, H, Sq/q_tile, Skv/kv_tile)`` with KV innermost. The fp32 running
-(m, l, acc) state lives in VMEM scratch across the KV sweep; the output block
-is written once on the final KV step (Pallas revisiting semantics keep the
-block resident until its index changes). fp32 statistics, MXU GEMMs with
-fp32 accumulation (``preferred_element_type``).
+Both: fp32 statistics, MXU GEMMs with fp32 accumulation
+(``preferred_element_type``) at ``KERNEL_PRECISION``.
 """
 from __future__ import annotations
 
@@ -67,14 +81,41 @@ NEG_INF = -1e30  # finite: keeps exp(s - m) NaN-free even for all-masked rows
 # ``jax_default_matmul_precision`` cannot reach the kernels: Mosaic refuses
 # bf16 operands at fp32 contract precision ("Bad lhs type").
 KERNEL_PRECISION = jax.lax.Precision.DEFAULT
+# Widest head group (H·D lanes of q, k and v) one forward grid step reads:
+# every head of the Evoformer's sites (256 lanes on the MSA side, 128 on the
+# pair side, 64 in the extra-MSA stack).
+MAX_GROUP_LANES = 256
+# Scoped VMEM of the forward: a whole-Sq q tile holds a (heads, Sq, Skv)
+# bias block and one head's (Sq, Skv) fp32 scores at a time; a v5e core has
+# 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 64 << 20
 
 
 def _pad_to(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
-def _flash_kernel(*refs, scale: float, kv_len: int, kv_tile: int,
-                  has_bias: bool, has_mask: bool):
+def head_group(heads: int, head_dim: int) -> int:
+    """Heads one forward grid step computes: all of them when their lanes
+    fit ``MAX_GROUP_LANES``, else the largest divisor of ``heads`` whose
+    lane width is a 128-lane multiple within it (a legal lane block), else
+    all of them."""
+    if heads * head_dim <= MAX_GROUP_LANES:
+        return heads
+    for g in range(heads - 1, 0, -1):
+        width = g * head_dim
+        if heads % g == 0 and width % LANE == 0 and width <= MAX_GROUP_LANES:
+            return g
+    return heads
+
+
+def _flash_kernel(*refs, scale: float, kv_len: int, kv_tile: int, n_kv: int,
+                  heads: int, head_dim: int, has_bias: bool,
+                  has_mask: bool):
+    """One (head group, row, q tile, kv tile) grid step over every head of
+    the group: static lane slices of the (q_tile | kv_tile, heads·D)
+    blocks. A single KV tile (``n_kv == 1``) takes the softmax in one pass;
+    several carry fp32 (m, l, acc) scratch across the innermost KV axis."""
     idx = 0
     q_ref = refs[idx]; idx += 1
     k_ref = refs[idx]; idx += 1
@@ -84,10 +125,61 @@ def _flash_kernel(*refs, scale: float, kv_len: int, kv_tile: int,
     mk_ref = refs[idx] if has_mask else None
     idx += int(has_mask)
     o_ref, lse_ref = refs[idx], refs[idx + 1]
-    acc_ref, m_ref, l_ref = refs[idx + 2], refs[idx + 3], refs[idx + 4]
+    acc_ref, m_ref, l_ref = refs[idx + 2:idx + 5] if n_kv > 1 else (None,) * 3
 
     jk = pl.program_id(3)
-    n_kv = pl.num_programs(3)
+    q, k, v = q_ref[0], k_ref[0], v_ref[0]     # (q_tile | kv_tile, heads·D)
+    mask = mk_ref[0].astype(jnp.float32) if has_mask else None  # (1, kv_tile)
+
+    def scores(h):
+        lanes = slice(h * head_dim, (h + 1) * head_dim)
+        s = jax.lax.dot_general(
+            q[:, lanes], k[:, lanes], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=KERNEL_PRECISION,
+        ) * scale                                     # (q_tile, kv_tile)
+        if b_ref is not None:
+            s = s + b_ref[0, h].astype(jnp.float32)
+        if mask is not None:
+            s = s + mask
+        if kv_len < n_kv * kv_tile:
+            # Padded KV columns must not win the max nor add to the sum.
+            col = jk * kv_tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(col < kv_len, s, NEG_INF)
+        return s
+
+    def pv(p, h):
+        return jax.lax.dot_general(
+            p.astype(v.dtype), v[:, h * head_dim:(h + 1) * head_dim],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=KERNEL_PRECISION,
+        )                                             # (q_tile, D)
+
+    def finish(accs, ms, ls):
+        outs, lses = [], []
+        for acc, m, l in zip(accs, ms, ls):
+            l = jnp.maximum(l, 1e-30)
+            outs.append(acc / l)
+            lses.append((m + jnp.log(l)).reshape(1, -1))
+        o_ref[0] = jnp.concatenate(outs, axis=-1).astype(o_ref.dtype)
+        lse_ref[0, 0] = jnp.concatenate(lses, axis=0)  # (heads, q_tile)
+
+    if n_kv == 1:
+        # The same softmax with nothing carried: no scratch round trips of
+        # (m, l, acc) nor alpha rescale. On a TPU v5e this runs the
+        # Evoformer's sites 1.76-2.04x faster than the scratch path below
+        # at one KV tile (MSA row, 512 rows: 2.78 against 4.89 ms a call).
+        accs, ms, ls = [], [], []
+        for h in range(heads):
+            s = scores(h)
+            m = jnp.max(s, axis=-1, keepdims=True)
+            p = jnp.exp(s - m)
+            ls.append(jnp.sum(p, axis=-1, keepdims=True))
+            ms.append(m)
+            accs.append(pv(p, h))
+        finish(accs, ms, ls)
+        return
 
     @pl.when(jk == 0)
     def _init():
@@ -95,47 +187,28 @@ def _flash_kernel(*refs, scale: float, kv_len: int, kv_tile: int,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0]                                   # (q_tile, d_pad)
-    k = k_ref[0, 0]                                   # (kv_tile, d_pad)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=KERNEL_PRECISION,
-    ) * scale                                         # (q_tile, kv_tile)
-    if b_ref is not None:
-        s = s + b_ref[0, 0].astype(jnp.float32)
-    if mk_ref is not None:
-        s = s + mk_ref[0].astype(jnp.float32)          # (1, kv_tile) row
-    # Neutralize KV padding: padded columns must not win the max nor
-    # contribute to the sum.
-    col = jk * kv_tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(col < kv_len, s, NEG_INF)
-
-    m_prev = m_ref[:, :1]                             # (q_tile, 1)
-    l_prev = l_ref[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=KERNEL_PRECISION,
-    )
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+    for h in range(heads):
+        s = scores(h)
+        m_prev = m_ref[h, :, :1]                      # (q_tile, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_ref[h, :, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + pv(p, h)
+        m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
     @pl.when(jk == n_kv - 1)
     def _epilogue():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[:, :1] + jnp.log(l)).reshape(1, -1)
+        finish([acc_ref[h] for h in range(heads)],
+               [m_ref[h, :, :1] for h in range(heads)],
+               [l_ref[h, :, :1] for h in range(heads)])
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("scale", "kv_len", "q_tile", "kv_tile", "has_bias",
-                     "has_mask", "interpret"),
+    static_argnames=("scale", "kv_len", "heads", "q_tile", "kv_tile",
+                     "has_bias", "has_mask", "interpret"),
 )
 def flash_attention_pallas(
     q: jax.Array,
@@ -146,64 +219,79 @@ def flash_attention_pallas(
     *,
     scale: float,
     kv_len: int,
+    heads: int,
     q_tile: int,
     kv_tile: int,
     has_bias: bool = False,
     has_mask: bool = False,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Pre-padded inputs only — see module docstring; use ops.fused_attention."""
-    n, h, sq, d = q.shape
-    skv = k.shape[2]
-    assert sq % q_tile == 0 and skv % kv_tile == 0 and d % LANE == 0, \
-        (q.shape, k.shape, q_tile, kv_tile)
-    grid = (n, h, sq // q_tile, skv // kv_tile)
+    """Forward on the model's layout — see the module docstring; use
+    ops.fused_attention. Returns (out (N, Sq, H·D) in q's dtype, lse
+    (N, H, Sq) fp32)."""
+    n, sq, width = q.shape
+    skv = k.shape[1]
+    assert sq % q_tile == 0 and skv % kv_tile == 0 and width % heads == 0, \
+        (q.shape, k.shape, heads, q_tile, kv_tile)
+    d = width // heads
+    g = head_group(heads, d)
+    n_kv = skv // kv_tile
+    # Head groups outermost, then rows: a pair bias shared by consecutive
+    # rows stays resident across them, and with one KV tile k and v stay
+    # resident across a row's q tiles; KV innermost carries the scratch.
+    grid = (heads // g, n, sq // q_tile, n_kv)
 
     in_specs = [
-        pl.BlockSpec((1, 1, q_tile, d), lambda i, j, iq, jk: (i, j, iq, 0)),
-        pl.BlockSpec((1, 1, kv_tile, d), lambda i, j, iq, jk: (i, j, jk, 0)),
-        pl.BlockSpec((1, 1, kv_tile, d), lambda i, j, iq, jk: (i, j, jk, 0)),
+        pl.BlockSpec((1, q_tile, g * d), lambda j, i, iq, jk: (i, iq, j)),
+        pl.BlockSpec((1, kv_tile, g * d), lambda j, i, iq, jk: (i, jk, j)),
+        pl.BlockSpec((1, kv_tile, g * d), lambda j, i, iq, jk: (i, jk, j)),
     ]
     operands = [q, k, v]
     if has_bias:
         assert bias is not None and bias.ndim == 4 and n % bias.shape[0] == 0
         rep = n // bias.shape[0]
         in_specs.append(
-            pl.BlockSpec((1, 1, q_tile, kv_tile),
-                         lambda i, j, iq, jk: (i // rep, j, iq, jk))
+            pl.BlockSpec((1, g, q_tile, kv_tile),
+                         lambda j, i, iq, jk: (i // rep, j, iq, jk))
         )
         operands.append(bias)
     if has_mask:
         assert mask is not None and mask.shape == (n, 1, skv)
         in_specs.append(
-            pl.BlockSpec((1, 1, kv_tile), lambda i, j, iq, jk: (i, 0, jk))
+            pl.BlockSpec((1, 1, kv_tile), lambda j, i, iq, jk: (i, 0, jk))
         )
         operands.append(mask)
 
+    scratch = []
+    if n_kv > 1:
+        scratch = [
+            pltpu.VMEM((g, q_tile, d), jnp.float32),      # acc
+            pltpu.VMEM((g, q_tile, LANE), jnp.float32),   # running max m
+            pltpu.VMEM((g, q_tile, LANE), jnp.float32),   # running sum l
+        ]
     kernel = functools.partial(
         _flash_kernel, scale=scale, kv_len=kv_len, kv_tile=kv_tile,
-        has_bias=has_bias, has_mask=has_mask,
+        n_kv=n_kv, heads=g, head_dim=d, has_bias=has_bias,
+        has_mask=has_mask,
     )
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, q_tile, d), lambda i, j, iq, jk: (i, j, iq, 0)),
-            pl.BlockSpec((1, 1, 1, q_tile), lambda i, j, iq, jk: (i, j, 0, iq)),
+            pl.BlockSpec((1, q_tile, g * d), lambda j, i, iq, jk: (i, iq, j)),
+            pl.BlockSpec((1, 1, g, q_tile), lambda j, i, iq, jk: (i, j, 0, iq)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((n, h, 1, sq), jnp.float32),
+            jax.ShapeDtypeStruct((n, heads // g, g, sq), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((q_tile, d), jnp.float32),      # acc
-            pltpu.VMEM((q_tile, LANE), jnp.float32),   # running max m
-            pltpu.VMEM((q_tile, LANE), jnp.float32),   # running sum l
-        ],
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*operands)
-    return out, lse.reshape(n, h, sq)
+    return out, lse.reshape(n, heads, sq)
 
 
 # ---------------------------------------------------------------------------

@@ -47,6 +47,7 @@ from repro.kernels.fused_elementwise import (
 )
 from repro.kernels.fused_softmax import fused_softmax_pallas
 from repro.kernels.layer_norm import layer_norm_pallas
+from repro.obs import trace as obs
 
 # Kernel envelope: last-dim sizes beyond this would blow the VMEM tile budget
 # on the v5e target (ROW_TILE rows * C * 4 B fp32 + headroom in ~16 MB VMEM).
@@ -235,6 +236,7 @@ def fused_softmax(
 _MAX_ATTN_D = 256
 _MAX_ATTN_S = 16384
 _DEFAULT_KV_TILE = 512   # forward KV tile / backward recompute block default
+_MAX_Q_TILE = 512        # forward q tile: all of Sq up to this many rows
 
 
 def _attn_envelope_ok(q_shape, kv_len: int | None = None, dtype=None) -> bool:
@@ -274,6 +276,23 @@ def _attn_tiles(sq: int, skv: int, d: int, kv_tile: int):
     return q_tile, kv, d_pad
 
 
+def _attn_fwd_tiles(sq: int, skv: int, kv_tile: int):
+    """(q_tile, kv_tile) of the forward kernel: one q tile covers all of Sq
+    up to ``_MAX_Q_TILE`` rows, so with one KV tile a (head group, row) is
+    one grid step. A longer Sq takes the 128-row multiple up to that size
+    that pads it least, then the largest (lse's block holds the q tile on
+    lanes). The KV tile is the backward's (``_attn_tiles``)."""
+    from repro.kernels.flash_attention import LANE, _pad_to
+
+    if sq <= _MAX_Q_TILE:
+        q_tile = _pad_to(sq, 16)
+    else:
+        q_tile = min(range(LANE, _MAX_Q_TILE + 1, LANE),
+                     key=lambda t: (_pad_to(sq, t), -t))
+    _, kv_t, _ = _attn_tiles(sq, skv, 0, kv_tile)
+    return q_tile, kv_t
+
+
 def _pad_nhsd(x, s_to: int, d_to: int):
     """Zero-pad a (N, H, S, D) kernel-layout tensor to (N, H, s_to, d_to)."""
     _, _, ss, dd = x.shape
@@ -283,11 +302,10 @@ def _pad_nhsd(x, s_to: int, d_to: int):
 
 
 def _attn_stage_padded(kv_tile, q, k, v, bias, mask):
-    """Shared fwd/bwd staging into the padded Pallas kernel layout — one
-    source of truth so the backward kernel always sees tiles padded under
-    the same rules as the forward that saved its residuals. Returns
-    (qt, kt, vt, bt, mt, q_tile, kv_t, sq_pad, skv_pad) with q/k/v
-    transposed to (N, H, S, D) and S/D padded to the tile grid."""
+    """Staging of the backward kernel's padded layout (the forward reads the
+    model's own). Returns (qt, kt, vt, bt, mt, q_tile, kv_t, sq_pad,
+    skv_pad) with q/k/v transposed to (N, H, S, D) and S/D padded to the
+    tile grid."""
     from repro.kernels.flash_attention import _pad_to
 
     n, sq, h, d = q.shape
@@ -329,16 +347,37 @@ def _attn_fwd_impl(scale, has_bias, has_mask, kv_tile, leg, q, k, v, bias,
         kvb = min(kv_tile or _DEFAULT_KV_TILE, skv)
         return flash_attention_xla(q, k, v, bias, mask, scale=scale,
                                    kv_tile=kvb)
-    from repro.kernels.flash_attention import flash_attention_pallas
+    from repro.kernels.flash_attention import (
+        _pad_to,
+        flash_attention_pallas,
+        head_group,
+    )
 
-    qt, kt, vt, bt, mt, q_tile, kv_t, sq_pad, skv_pad = _attn_stage_padded(
-        kv_tile, q, k, v, bias, mask)
+    # The model's layout: (N, S, H, D) -> (N, S, H·D) is a free reshape; S
+    # is padded only where it is not a tile multiple.
+    q_tile, kv_t = _attn_fwd_tiles(sq, skv, kv_tile)
+    sq_pad, skv_pad = _pad_to(sq, q_tile), _pad_to(skv, kv_t)
+
+    def rows(x, s_to):
+        x = x.reshape(n, x.shape[1], h * d)
+        return jnp.pad(x, ((0, 0), (0, s_to - x.shape[1]), (0, 0)))
+
+    if bias is not None:
+        bias = jnp.pad(bias, ((0, 0), (0, 0), (0, sq_pad - sq),
+                              (0, skv_pad - skv)))
+    if mask is not None:
+        mask = jnp.pad(mask, ((0, 0), (0, skv_pad - skv)))[:, None, :]
+    # Trace-time counter, one per flash call of the program: every head in
+    # one grid step over one KV tile is the resident order.
+    obs.count("ops.attention.fwd", leg=leg, heads=h,
+              heads_per_step=head_group(h, d), kv_tiles=skv_pad // kv_t)
     out, lse = flash_attention_pallas(
-        qt, kt, vt, bt, mt, scale=scale, kv_len=skv, q_tile=q_tile,
-        kv_tile=kv_t, has_bias=bias is not None, has_mask=mask is not None,
+        rows(q, sq_pad), rows(k, skv_pad), rows(v, skv_pad), bias, mask,
+        scale=scale, kv_len=skv, heads=h, q_tile=q_tile, kv_tile=kv_t,
+        has_bias=bias is not None, has_mask=mask is not None,
         interpret=_interpret_for(leg),
     )
-    return out[:, :, :sq, :d].transpose(0, 2, 1, 3), lse[:, :, :sq]
+    return out[:, :sq].reshape(n, sq, h, d), lse[:, :, :sq]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))

@@ -180,12 +180,14 @@ def evoformer_peak_bytes(
 
     cfg: EvoformerConfig (duck-typed: d_msa, d_pair, msa_heads, pair_heads,
     head_dim, opm_dim, tri_mult_dim, transition_factor, compute_dtype).
-    ``staged``: the fused leg is the Pallas kernel, which stages q, k, v and
-    its output with each head padded to 128 lanes (``ops._attn_tiles``), so
-    every attention call is counted at that width: 4x the bytes of a 32-wide
-    head, 16x those of the extra-MSA stack's 8-wide heads over 5120 rows.
-    The XLA leg stages the heads at their width. Returns a dict of named
-    terms; ``sum(values())`` is the modeled peak.
+    ``staged``: the fused leg is the Pallas kernel, and every attention call
+    is counted with each head padded to 128 lanes (``ops._attn_tiles``): 4x
+    the bytes of a 32-wide head, 16x those of the extra-MSA stack's 8-wide
+    heads over 5120 rows. Only the backward kernel still stages that way;
+    the forward reads q, k, v and writes its output at the heads' width, so
+    for a forward-only pass the term overcounts. The XLA leg stages the
+    heads at their width. Returns a dict of named terms; ``sum(values())``
+    is the modeled peak.
     """
     dt = jnp.dtype(cfg.compute_dtype).itemsize
     s_loc = _ceil_div(n_seq, dap)

@@ -114,3 +114,25 @@ def test_masked_positions_do_not_leak(inputs):
                              pair_mask, cfg=CFG)
     np.testing.assert_allclose(np.asarray(m1[:, :, :-2]),
                                np.asarray(m2[:, :, :-2]), atol=2e-4)
+
+
+@pytest.mark.parametrize("qkv_bias", [False, True])
+def test_project_heads_matches_merged_projection(qkv_bias):
+    """The Evoformer's per-output QKV GEMMs give the merged projection's q,
+    k and v (``layers/attention.project_qkv``), with or without a bias."""
+    from repro.core.evoformer import _project_heads
+    from repro.layers.attention import AttnDims, init_attention, project_qkv
+
+    dims = AttnDims(4, 4, 8)
+    p = init_attention(jax.random.PRNGKey(0), 32, 4, 4, 8, gating=True,
+                       qkv_bias=qkv_bias)
+    if qkv_bias:
+        p["wqkv"]["b"] = jax.random.normal(jax.random.PRNGKey(2),
+                                           p["wqkv"]["b"].shape)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 3, 10, 32))
+    want = project_qkv(p, x, dims, compute_dtype=x.dtype)
+    got = _project_heads(p, x, dims)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape == (2, 3, 10, 4, 8)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-6,
+                                   atol=1e-6)

@@ -239,6 +239,91 @@ def test_fused_pallas_backward_matches_scan_bf16(monkeypatch):
         assert float(np.abs(a - b).max()) <= 2e-2 * scale
 
 
+# The forward kernel on the model's (N, S, H·D) layout, every head of a row
+# in one grid step (interpret mode): (n, sq, skv, h, d, bias_b, kv_tile).
+PACKED_FWD_CASES = {
+    # H·D 256 (the MSA sites), bias shared by 2 rows each, Sq 33 padded to
+    # the 48-row q tile.
+    "hd256_shared_bias": (4, 33, 33, 8, 32, 2, 0),
+    # H·D 128 (the pair sites), KV tile 128 < Skv 300: three KV tiles, the
+    # last padded, swept innermost with the fp32 state in scratch.
+    "hd128_kv_tiles": (2, 40, 300, 4, 32, 1, 128),
+    # H·D 64 (the extra-MSA stack), one bias row per attention row.
+    "hd64_row_bias": (3, 20, 20, 8, 8, 3, 0),
+    # Sq 530 over five 128-row q tiles, the last padded; Skv 130 padded to
+    # one 256-wide KV tile.
+    "hd32_q_tiles": (2, 530, 130, 4, 8, 1, 0),
+    # Sq 1100 over three 384-row q tiles; Skv 700 over two 512-wide KV
+    # tiles, the second padded.
+    "hd128_q_and_kv_tiles": (1, 1100, 700, 4, 32, 1, 0),
+    # H·D 512 past the widest group: two groups of two 128-wide heads.
+    "hd512_head_groups": (2, 24, 40, 4, 128, 1, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PACKED_FWD_CASES))
+def test_packed_forward_matches_ref(case):
+    n, sq, skv, h, d, bias_b, kv_tile = PACKED_FWD_CASES[case]
+    q, k, v, bias, mask = _mk(n, sq, skv, h, d, jnp.float32, True, True,
+                              bias_b=bias_b, seed=21)
+    scale = d ** -0.5
+    with use_plan(preset("interpret")):
+        got = ops.fused_attention(q, k, v, bias=bias, mask=mask, scale=scale,
+                                  kv_tile=kv_tile)
+    want, _ = ref.attention_ref(q, k, v, bias, mask, scale)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
+                               rtol=1e-4)
+
+
+@pytest.mark.parametrize("sq,q_tile", [
+    (33, 48), (256, 256), (512, 512),       # one q tile covers all of Sq
+    (530, 128), (600, 128), (768, 384),     # the least padding, 128-row
+    (1024, 512), (1100, 384),               # multiples, then the largest
+])
+def test_forward_q_tile(sq, q_tile):
+    assert ops._attn_fwd_tiles(sq, 256, 0)[0] == q_tile
+
+
+@pytest.mark.parametrize("heads,head_dim,group", [
+    (8, 32, 8), (4, 32, 4), (8, 8, 8),     # the Evoformer's sites: all heads
+    (4, 128, 2), (16, 32, 8),              # 256-lane groups past the widest
+    (3, 96, 3),                            # no 128-lane divisor: all heads
+])
+def test_head_group(heads, head_dim, group):
+    from repro.kernels.flash_attention import head_group
+
+    assert head_group(heads, head_dim) == group
+
+
+def test_packed_forward_lse_and_grad_through_staged_backward():
+    """The packed forward's lse residual, and the gradient through the
+    backward kernel that still reads the staged (N, H, S, 128-lane) layout,
+    against the oracle: H·D 64 over two KV tiles."""
+    n, sq, skv, h, d = 2, 24, 200, 4, 16
+    q, k, v, bias, mask = _mk(n, sq, skv, h, d, jnp.float32, True, True,
+                              bias_b=1, seed=23)
+    scale = 0.4
+    out, lse = ops._attn_fwd_impl(scale, True, True, 128, "interpret", q, k,
+                                  v, bias, mask)
+    want_out, want_lse = ref.attention_ref(q, k, v, bias, mask, scale)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
+                               atol=2e-5, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               atol=2e-5, rtol=1e-5)
+
+    def loss(q_, k_, v_, b_, m_):
+        return jnp.sum(jnp.sin(ops.fused_attention(
+            q_, k_, v_, bias=b_, mask=m_, scale=scale, kv_tile=128)))
+
+    with use_plan(preset("interpret")):
+        got = jax.grad(loss, argnums=(0, 1, 2, 3, 4))(q, k, v, bias, mask)
+    want = ref.attention_bwd_ref(q, k, v, bias, mask, jnp.cos(want_out),
+                                 scale)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5,
+                                   rtol=1e-3)
+
+
 def test_fused_attention_disabled_matches_kernel():
     """The 'oracle' plan's fallback == the kernel path (A/B as a use_plan
     scope instead of the old KERNELS_ENABLED mutation)."""
@@ -331,3 +416,31 @@ def test_evoformer_block_bf16_grad_parity(block_inputs):
         # A/B delta here is bf16 rounding noise, not a defect.
         scale = max(1.0, float(np.abs(b).max()))
         assert float(np.abs(a - b).max()) <= 4e-2 * scale
+
+
+def test_attention_counter_one_block(block_inputs):
+    """Tracing one Evoformer block on the kernel's leg records
+    ``ops.attention.fwd`` once per attention site (MSA row and column,
+    triangle start and end), each with every head in one grid step and one
+    KV tile; nothing on the XLA and oracle legs, which run no flash
+    kernel."""
+    from repro.obs import use_tracer
+
+    params = init_evoformer_block(jax.random.PRNGKey(0), CFG)
+
+    def trace_block():
+        with use_tracer() as tr:
+            jax.eval_shape(lambda p, *a: evoformer_block(
+                p, *a, dist=LocalDist(), cfg=CFG), params, *block_inputs)
+        return [e["attrs"] for e in tr.events
+                if e["kind"] == "counter" and e["name"] == "ops.attention.fwd"]
+
+    with use_plan(preset("interpret")):
+        got = trace_block()
+    heads = [CFG.msa_heads] * 2 + [CFG.pair_heads] * 2
+    assert sorted(a["heads"] for a in got) == sorted(heads)
+    for a in got:
+        assert a["heads_per_step"] == a["heads"] and a["kv_tiles"] == 1, a
+    for leg in ("xla", "oracle"):
+        with use_plan(current_plan().with_kernels(attention=leg)):
+            assert trace_block() == []

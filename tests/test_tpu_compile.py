@@ -33,12 +33,15 @@ BF16, F32 = jnp.bfloat16, jnp.float32
 # (N, S, H, D, bias, mask) of the Evoformer's attention sites at FULL
 # widths, n_res 256, n_seq 128: N is batch x group, S the attended length;
 # and the extra-MSA stack's row attention (model_3: 5120 rows, 8 heads of
-# 8), which the kernel stages at 128 lanes as every site.
+# 8); and triangle attention at n_res 600, whose forward takes several q
+# and KV tiles. The forward reads them in the model's (N, S, H·D) layout;
+# the backward stages them at 128 lanes a head.
 ATTENTION_SITES = {
     "msa_row": (128, 256, 8, 32, True, True),
     "msa_col": (256, 128, 8, 32, False, True),
     "tri_attn": (256, 256, 4, 32, True, True),
     "extra_msa_row": (5120, 256, 8, 8, True, True),
+    "tri_attn_r600": (600, 600, 4, 32, True, True),
 }
 HEAD_DIM = 32
 
@@ -80,6 +83,7 @@ def _assert_kernels(hlo: str, n: int):
 
 
 def _attention_shapes(site):
+    """The backward kernel's staged shapes: (N, H, S, 128-lane D)."""
     n, s, h, d, has_bias, has_mask = ATTENTION_SITES[site]
     q_tile, kv_tile, d_pad = ops._attn_tiles(s, s, d, 0)
     sq, skv = -(-s // q_tile) * q_tile, -(-s // kv_tile) * kv_tile
@@ -95,10 +99,57 @@ def _attention_shapes(site):
 
 @pytest.mark.parametrize("site", sorted(ATTENTION_SITES))
 def test_flash_attention_fwd_compiles(one_chip, site):
-    q, kv, _, bias, mask, kw = _attention_shapes(site)
+    """The forward on the model's layout: q, k, v (N, S, H·D), every head
+    of a row in one grid step."""
+    n, s, h, d, has_bias, has_mask = ATTENTION_SITES[site]
+    q_tile, kv_tile = ops._attn_fwd_tiles(s, s, 0)
+    sq, skv = -(-s // q_tile) * q_tile, -(-s // kv_tile) * kv_tile
+    kw = dict(scale=d ** -0.5, kv_len=s, heads=h, q_tile=q_tile,
+              kv_tile=kv_tile, has_bias=has_bias, has_mask=has_mask,
+              interpret=False)
     hlo = _compile(functools.partial(flash_attention_pallas, **kw), one_chip,
-                   q, kv, kv, bias, mask)
+                   ((n, sq, h * d), BF16), ((n, skv, h * d), BF16),
+                   ((n, skv, h * d), BF16),
+                   ((1, h, sq, skv), BF16) if has_bias else None,
+                   ((n, 1, skv), F32) if has_mask else None)
     _assert_kernels(hlo, 1)
+
+
+def test_msa_row_attention_layer_reads_model_layout(one_chip, monkeypatch):
+    """The Evoformer's gated attention at the ``msa_row`` site, from the QKV
+    projection through ``ops.fused_attention`` to the gated output
+    projection, compiled on the Pallas leg: one kernel, and no transpose or
+    pad of q, k or v between the projection and it, nor a slice of one
+    merged projection output."""
+    from repro.core.evoformer import _gated_attention
+    from repro.exec.plan import current_plan, use_plan
+    from repro.layers.attention import AttnDims, init_attention
+
+    n, s, h, d, _, _ = ATTENTION_SITES["msa_row"]
+    c = h * d
+    dims = AttnDims(h, h, d)
+    # ``ops`` interprets the kernels off-TPU; this compile is for the chip.
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    params = jax.eval_shape(lambda: init_attention(
+        jax.random.PRNGKey(0), c, h, h, d, gating=True, out_bias=True))
+
+    def layer(p, x, bias, mask):
+        return _gated_attention(p, x, bias, mask, dims)
+
+    with use_plan(current_plan().with_kernels(attention="pallas")):
+        lowered = jax.jit(layer).lower(
+            jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=one_chip), params),
+            jax.ShapeDtypeStruct((1, n, s, c), BF16, sharding=one_chip),
+            jax.ShapeDtypeStruct((1, h, s, s), BF16, sharding=one_chip),
+            jax.ShapeDtypeStruct((1, n, s), F32, sharding=one_chip))
+    hlo = lowered.compile().as_text()
+    _assert_kernels(hlo, 1)
+    for op in ("transpose", "pad"):
+        assert f" {op}(" not in hlo, op
+    # The projection's outputs reach the kernel whole: no sliced (N, S,
+    # 3·H·D) output of a merged GEMM.
+    assert f",{s},{3 * c}]" not in hlo
 
 
 @pytest.mark.parametrize("site", sorted(ATTENTION_SITES))
